@@ -61,9 +61,8 @@ def _bench_dense(rng) -> None:
         # Interleaved min-of-N: separately-timed blocks on a shared box
         # measure scheduler bursts, not the kernels — an earlier artifact
         # recorded the fused path ~10% "slower" at the small shape from
-        # exactly that (on CPU both paths dispatch IDENTICAL work: the
-        # fused epilogue only exists in the Pallas kernels, and impl="ref"
-        # packs via the same pack_codes either way).
+        # exactly that (both paths dispatch IDENTICAL work: every impl
+        # packs via the same pack_codes, inside or after its jit).
         for pb in (8,):
             fuse_fn = lambda: dispatch.signatures_dense(v, pi, k, pack_b=pb)
             two_fn = lambda: ops.pack_codes(

@@ -17,6 +17,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="1 warmup, 1 iter, tiny shapes (CI regression mode)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.smoke:
         from benchmarks import common
         common.set_smoke(True)

@@ -24,10 +24,31 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12     # bf16 / chip
-HBM_BW = 819e9          # bytes/s / chip
-ICI_BW = 50e9           # bytes/s / link (1 link charged)
-HBM_PER_CHIP = 16e9     # v5e HBM capacity
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s inter-chip interconnect).
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bw": 819e9, "hbm_bytes": 16e9, "ici_bw": 200e9},
+}
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """The peak table row for a device; an unknown kind is an error, never
+    a silent default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
+
+
+# the dry-run models a v5e pod (the assignment's hardware model)
+_V5E = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["bf16_flops"]     # bf16 / chip
+HBM_BW = _V5E["hbm_bw"]             # bytes/s / chip
+ICI_BW = 50e9                       # bytes/s / link (1 link charged)
+HBM_PER_CHIP = _V5E["hbm_bytes"]
 
 
 def model_flops(rec: dict) -> float:

@@ -5,7 +5,10 @@ permutations and routes every batch — dense or sparse — through the kernel
 dispatch layer (``kernels.dispatch``: shape/backend implementation selection
 plus autotuned block sizes), sharded over the ``data`` mesh axis with
 pi/sigma replicated — they are the whole point: two vectors, trivially
-replicable even at D = 2^30.
+replicable even at D = 2^30.  On a mesh the signing call runs under
+``jax.shard_map``: each device signs its own batch shard with the kernel on
+local shapes, because a Pallas (Mosaic) call is opaque to XLA's SPMD
+partitioner and cannot be split across devices by it.
 
 ``sign_packed`` is the fused ingest path: signatures leave the kernel already
 truncated to b bits and packed into uint32 words (``SketchStore.add_packed``
@@ -55,13 +58,15 @@ class SketchEngine:
 
         if mesh is not None:
             batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-            self._data_sharding = NamedSharding(mesh, P(batch_axes))
+            self._batch_spec = P(batch_axes)
+            self._data_sharding = NamedSharding(mesh, self._batch_spec)
             self._rep_sharding = NamedSharding(mesh, P())
             self.pi = jax.device_put(self.pi, self._rep_sharding)
             if self.sigma is not None:
                 self.sigma = jax.device_put(self.sigma, self._rep_sharding)
         else:
             self._data_sharding = None
+        self._mesh_fns: dict = {}       # (layout, pack_b) -> jit(shard_map)
         # sign-call counters (dispatch counts per resolved kernel impl;
         # these count what the engine was ASKED, rows included, so
         # rows/impl ratios read straight off one snapshot)
@@ -75,13 +80,7 @@ class SketchEngine:
         words when ``pack_b`` is set — the fused sign->pack kernel path)."""
         self._c_dense.inc()
         self._c_rows.inc(v.shape[0])
-        if self._data_sharding is not None:
-            v = jax.device_put(v, self._data_sharding)
-        return dispatch.signatures_dense(
-            v, self.pi, self.cfg.k, self.sigma,
-            use_kernel=self.cfg.use_kernel, pack_b=pack_b,
-            block_b=self.cfg.block_b, block_d=self.cfg.block_d,
-            autotune_measure=self.cfg.autotune_measure)
+        return self._signed("dense", v, pack_b)
 
     def signatures_sparse(self, idx: Array, *,
                           pack_b: int | None = None) -> Array:
@@ -89,21 +88,44 @@ class SketchEngine:
         uint32 packed words when ``pack_b`` is set)."""
         self._c_sparse.inc()
         self._c_rows.inc(idx.shape[0])
-        if self._data_sharding is not None:
-            idx = jax.device_put(idx, self._data_sharding)
+        return self._signed("sparse", idx, pack_b)
+
+    def _sign_local(self, layout: str, x: Array, pi: Array,
+                    sigma: Array | None, pack_b: int | None) -> Array:
+        """One device's signing call through the dispatch front door."""
+        cfg = self.cfg
+        if layout == "dense":
+            return dispatch.signatures_dense(
+                x, pi, cfg.k, sigma, use_kernel=cfg.use_kernel,
+                pack_b=pack_b, block_b=cfg.block_b, block_d=cfg.block_d,
+                autotune_measure=cfg.autotune_measure)
         return dispatch.signatures_sparse(
-            idx, self.pi, self.cfg.k, self.sigma,
-            use_kernel=self.cfg.use_kernel, pack_b=pack_b,
-            block_b=self.cfg.block_b, block_j=self.cfg.block_j,
-            autotune_measure=self.cfg.autotune_measure)
+            x, pi, cfg.k, sigma, use_kernel=cfg.use_kernel, pack_b=pack_b,
+            block_b=cfg.block_b, block_j=cfg.block_j,
+            autotune_measure=cfg.autotune_measure)
+
+    def _signed(self, layout: str, x: Array, pack_b: int | None) -> Array:
+        if self.mesh is None:
+            return self._sign_local(layout, x, self.pi, self.sigma, pack_b)
+        perms = (self.pi,) if self.sigma is None else (self.pi, self.sigma)
+        fn = self._mesh_fns.get((layout, pack_b))
+        if fn is None:
+            def body(x, pi, *sigma):
+                return self._sign_local(layout, x, pi,
+                                        sigma[0] if sigma else None, pack_b)
+            fn = jax.jit(jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(self._batch_spec,) + (P(),) * len(perms),
+                out_specs=self._batch_spec, check_vma=False))
+            self._mesh_fns[(layout, pack_b)] = fn
+        return fn(jax.device_put(x, self._data_sharding), *perms)
 
     def sign_packed(self, data: Array, b: int, *,
                     layout: str = "dense") -> Array:
         """Fused sign->pack ingest: data -> (B, ceil(K/(32/b))) uint32 words.
 
-        Bit-identical to ``pack_codes(signatures_*(data), b)`` but the dense
-        kernels pack in their epilogue and the sparse window-min kernels
-        pack inside the same compiled scan — no (B, K) int32 on the host.
+        Bit-identical to ``pack_codes(signatures_*(data), b)``; every impl
+        packs inside its own jit — no (B, K) int32 on the host.
         Feed the result to ``SketchStore.add_packed``.
         """
         if layout == "dense":

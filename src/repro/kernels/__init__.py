@@ -2,7 +2,7 @@
 
 Signing requests route through ``dispatch`` (see README.md for the policy);
 ``autotune`` owns block-size selection; ``packfmt`` is the b-bit packed-code
-format shared by the store and the fused in-kernel sign->pack epilogue.
+format shared by the store, the scorers and the signing paths' ``pack_b``.
 """
 
 from .ops import (cminhash_signatures, cminhash_signatures_packed,  # noqa: F401

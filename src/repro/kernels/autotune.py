@@ -57,10 +57,13 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 KINDS = ("dense_int8", "dense_packed", "sparse_pallas", "sparse_windows",
          "query_fold", "probe_pallas")
 
+# defaults tile the TPU's (8, 128) layout wherever the block is a sublane or
+# lane dim of a Pallas block (block_b, block_d, block_j); block_q is rounded
+# up to whole (8, 128) tiles inside the fold kernel
 _DEFAULTS: dict[str, dict[str, int]] = {
     "dense_int8": {"block_b": 8, "block_d": 256},
     "dense_packed": {"block_b": 8, "block_d": 256},
-    "sparse_pallas": {"block_b": 8, "block_j": 32},
+    "sparse_pallas": {"block_b": 8, "block_j": 128},
     "sparse_windows": {"block_j": 64},
     "query_fold": {"block_q": 128},
     "probe_pallas": {"block_e": 128},
@@ -78,6 +81,25 @@ _CANDIDATES: dict[str, tuple[dict[str, int], ...]] = {
     "query_fold": tuple({"block_q": bq} for bq in (32, 64, 128, 256, 512)),
     "probe_pallas": tuple({"block_e": be} for be in (32, 64, 128, 256, 512)),
 }
+
+# On TPU only blocks that tile (8, 128) compile.  The packed dense kernel
+# and the Pallas probe are never dispatched there (kernels/dispatch.py), so
+# they have nothing to sweep.
+_TPU_CANDIDATES: dict[str, tuple[dict[str, int], ...]] = {
+    "dense_int8": tuple({"block_b": bb, "block_d": bd}
+                        for bb in (8, 16, 32) for bd in (128, 256, 512)),
+    "dense_packed": (),
+    "sparse_pallas": tuple({"block_b": bb, "block_j": bj}
+                           for bb in (8, 16) for bj in (128, 256)),
+    "sparse_windows": _CANDIDATES["sparse_windows"],
+    "query_fold": tuple({"block_q": bq} for bq in (32, 64, 128, 256, 512)),
+    "probe_pallas": (),
+}
+
+
+def _candidates_for(kind: str, backend: str) -> tuple[dict[str, int], ...]:
+    """The default sweep field for ``kind`` on ``backend``."""
+    return (_TPU_CANDIDATES if backend == "tpu" else _CANDIDATES)[kind]
 
 _cache: dict[str, dict[str, int]] = {}
 _loaded_paths: set[str] = set()
@@ -146,14 +168,17 @@ def cached(kind: str, b: int, d: int, k: int, backend: str | None = None,
 
 
 def _clamp(kind: str, blocks: dict[str, int], b: int, d: int,
-           k: int) -> dict[str, int]:
+           k: int, backend: str = "cpu") -> dict[str, int]:
     out = dict(blocks)
     if "block_b" in out:
+        # a batch tile of pow2(b) >= b rows is the whole padded batch, which
+        # TPU accepts at any size; larger batches keep the aligned tile
         out["block_b"] = max(1, min(out["block_b"], _pow2(b)))
     if "block_d" in out:
-        # dense kernels want block_d % 32 == 0 (bit-packed words / pack
-        # epilogue); never clamp below 32
-        out["block_d"] = max(32, min(out["block_d"], _pow2(max(d, 32))))
+        # dense kernels want block_d % 32 == 0 (bit-packed words), and a
+        # whole 128-lane tile on TPU; never clamp below that
+        lane = 128 if backend == "tpu" else 32
+        out["block_d"] = max(lane, min(out["block_d"], _pow2(max(d, lane))))
     if "block_j" in out:
         out["block_j"] = max(1, out["block_j"])
     if "block_q" in out:
@@ -173,9 +198,9 @@ def recommend(kind: str, b: int, d: int, k: int,
     hit = cached(kind, b, d, k, backend, nnz)
     if hit is not None:
         obs_metrics.default().counter("autotune.hit").inc()
-        return _clamp(kind, hit, b, d, k)
+        return _clamp(kind, hit, b, d, k, backend)
     obs_metrics.default().counter("autotune.heuristic").inc()
-    return _clamp(kind, _DEFAULTS[kind], b, d, k)
+    return _clamp(kind, _DEFAULTS[kind], b, d, k, backend)
 
 
 def _make_runner(kind: str, b: int, d: int, k: int, nnz: int,
@@ -237,18 +262,27 @@ def _sweep(runner: Callable[[dict[str, int]], Any],
     convention bench_sign.py uses (see kernels/dispatch.py).  A candidate
     that raises during warmup is dropped (invalid on this backend); one that
     raises mid-round keeps its best earlier time.  Returns the fastest
-    ``(seconds, blocks)`` or None when nothing ran."""
+    ``(seconds, blocks)`` or None when nothing ran.
+
+    Raises when no candidate compiles at all: a kernel that lowers at no
+    block size is broken on this backend, not a tuning result."""
     import math
 
     live: list[tuple[dict[str, int], Any, list[float]]] = []
+    errors: list[str] = []
     for blocks in cands:
         fn = runner(blocks)
         try:
             for _ in range(max(warmup, 1)):
                 jax.block_until_ready(fn())
-        except Exception:
-            continue                       # candidate invalid on this backend
+        except Exception as e:             # candidate invalid on this backend
+            errors.append(f"{blocks}: {type(e).__name__}: {e}"[:300])
+            continue
         live.append((blocks, fn, [math.inf]))
+    if not live:
+        raise RuntimeError(
+            f"no block candidate compiled on {jax.default_backend()} "
+            f"({len(cands)} tried): " + "; ".join(errors[:3]))
     for _ in range(max(iters, 1)):
         for blocks, fn, t in live:
             try:
@@ -302,12 +336,13 @@ def measure(kind: str, b: int, d: int, k: int, *, backend: str | None = None,
     sweep_t0 = time.perf_counter()
     runner = _make_runner(kind, b, d, k, nnz, seed)
     guard = candidates is None
-    default = _clamp(kind, _DEFAULTS[kind], b, d, k)
+    default = _clamp(kind, _DEFAULTS[kind], b, d, k, backend)
     field: list[dict[str, int]] = []
     seen: set[tuple] = set()     # clamping can collapse candidates; time once
-    pool = _CANDIDATES[kind] + (default,) if guard else candidates
+    pool = _candidates_for(kind, backend) + (default,) if guard \
+        else candidates
     for cand in pool:
-        blocks = _clamp(kind, cand, b, d, k)
+        blocks = _clamp(kind, cand, b, d, k, backend)
         key = tuple(sorted(blocks.items()))
         if key in seen or not _valid(kind, blocks, b, d, k):
             continue

@@ -15,8 +15,11 @@ across the innermost grid dimension.
 
 VMEM working set per program instance (defaults Bt=8, Dt=Kt=256):
   band 2*Bt*Dt int8 + pi Dt int32 + acc Bt*Kt int32 ≈ 13 KB  — far under budget;
-larger Dt (512/1024) trades grid steps for VMEM and stays aligned to the 128-lane
-VPU geometry (Dt % 128 == 0).
+larger Dt (512/1024) trades grid steps for VMEM.  On TPU the blocks must tile
+(8, 128): Bt % 8 == 0 and Dt % 128 == 0.  The window at offset q is a lane
+rotation of the band and hash q lands in its column by an iota select —
+Mosaic lowers neither a value-level dynamic slice nor a dynamic-column
+update.
 """
 
 from __future__ import annotations
@@ -28,45 +31,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .packfmt import pack_block, pack_geometry
+from .packfmt import pack_codes
 
 Array = jax.Array
 SENTINEL = jnp.iinfo(jnp.int32).max
 
 
-def _kernel(pi_ref, vlo_ref, vhi_ref, out_ref, acc_scratch=None, *, bt: int,
-            dt: int, off: int, nd: int = 0, k: int = 0,
-            pack_b: int | None = None):
-    d_idx = pl.program_id(2)
-    # plain mode: accumulate straight into the int32 output block.  fused
-    # pack mode: accumulate in a VMEM scratch (re-initialized whenever the
-    # innermost data dim restarts) so the only HBM output is the packed words
-    acc_ref = out_ref if pack_b is None else acc_scratch
-
-    @pl.when(d_idx == 0)
+def _kernel(pi_ref, vlo_ref, vhi_ref, out_ref, *, bt: int, dt: int,
+            off: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        acc_ref[...] = jnp.full(acc_ref.shape, SENTINEL, acc_ref.dtype)
+        out_ref[...] = jnp.full(out_ref.shape, SENTINEL, out_ref.dtype)
 
-    band = jnp.concatenate([vlo_ref[...], vhi_ref[...]], axis=1)  # (Bt, 2*Dt) int8
-    pvals = pi_ref[...]  # (Dt,) int32
+    # (Bt, 2*Dt) band as int32 lanes: Mosaic rotates 32-bit lanes only
+    band = jnp.concatenate([vlo_ref[...], vhi_ref[...]],
+                           axis=1).astype(jnp.int32)
+    pvals = pi_ref[...]                                    # (1, Dt) int32
+    col = jax.lax.broadcasted_iota(jnp.int32, (bt, dt), 1)
 
     def body(k_local, acc):
-        window = jax.lax.dynamic_slice(band, (0, k_local + off), (bt, dt))
-        masked = jnp.where(window > 0, pvals[None, :], SENTINEL)
-        return acc.at[:, k_local].min(jnp.min(masked, axis=1))
+        # window = band[:, k_local + off :][:, :Dt] as a lane rotation (no
+        # value-level dynamic slice, which Mosaic does not lower)
+        shift = (2 * dt - (k_local + off)) % (2 * dt)
+        window = pltpu.roll(band, shift, 1)[:, :dt]
+        masked = jnp.where(window > 0, pvals, SENTINEL)
+        hk = jnp.min(masked, axis=1, keepdims=True)        # (Bt, 1)
+        return jnp.where(col == k_local, jnp.minimum(acc, hk), acc)
 
-    acc_ref[...] = jax.lax.fori_loop(0, dt, body, acc_ref[...])
-
-    if pack_b is not None:
-        # fused sign->pack epilogue: once the min over the last data block is
-        # folded in, truncate to b bits and pack — the (B, K) int32 form never
-        # leaves VMEM.  (program_id must be read outside the pl.when closure:
-        # interpret mode does not rewrite it inside cond branches.)
-        col0 = pl.program_id(1) * dt
-
-        @pl.when(d_idx == nd - 1)
-        def _pack():
-            out_ref[...] = pack_block(acc_ref[...], col0, k=k, b=pack_b)
+    out_ref[...] = jax.lax.fori_loop(0, dt, body, out_ref[...])
 
 
 @functools.partial(
@@ -82,10 +74,9 @@ def cminhash_pallas(v: Array, pi: Array, k: int, *, shift_offset: int = 1,
 
     v: (B, D) int8/bool/int32 binary data (already sigma-permuted by the caller);
     pi: (D,) int32 permutation values. Returns (B, K) int32 with column q holding
-    the paper's h_{q+shift_offset} — unless ``pack_b`` is set, in which case the
-    fused epilogue truncates each hash to its lowest pack_b bits and returns the
-    (B, ceil(K / (32/pack_b))) uint32 packed words directly (bit-identical to
-    sign-then-``packfmt.pack_codes``); requires block_d % (32/pack_b) == 0.
+    the paper's h_{q+shift_offset} — unless ``pack_b`` is set, in which case
+    the (B, ceil(K / (32/pack_b))) uint32 packed words come back instead
+    (``packfmt.pack_codes`` on the kernel's mins, inside the same jit).
     """
     if shift_offset not in (0, 1):
         raise ValueError("shift_offset must be 0 or 1 (band fits 2 blocks)")
@@ -115,33 +106,17 @@ def cminhash_pallas(v: Array, pi: Array, k: int, *, shift_offset: int = 1,
     vpad = vpad.at[:b, d:d + wrap].set(mask[:, :wrap])
 
     grid = (nb, nk, nd)
-    in_specs = [
-        pl.BlockSpec((dt,), lambda i, j, dd: (dd,)),
-        pl.BlockSpec((bt, dt), lambda i, j, dd: (i, dd + j)),
-        pl.BlockSpec((bt, dt), lambda i, j, dd: (i, dd + j + 1)),
-    ]
-    sig_spec = pl.BlockSpec((bt, kt), lambda i, j, dd: (i, j))
-    sig_shape = jax.ShapeDtypeStruct((nb * bt, nk * kt), jnp.int32)
-
-    if pack_b is None:
-        out = pl.pallas_call(
-            functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset),
-            grid=grid, in_specs=in_specs, out_specs=sig_spec,
-            out_shape=sig_shape, interpret=interpret,
-        )(pi_pad, vpad, vpad)
-        return out[:b, :k]
-
-    cpw, n_words = pack_geometry(k, pack_b)
-    if kt % cpw:
-        raise ValueError(
-            f"block_d={dt} must be a multiple of {cpw} for pack_b={pack_b}")
-    words = pl.pallas_call(
-        functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset, nd=nd,
-                          k=k, pack_b=pack_b),
-        grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, kt // cpw), lambda i, j, dd: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nb * bt, nk * kt // cpw), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((bt, kt), jnp.int32)],
+    out = pl.pallas_call(
+        functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, dt), lambda i, j, dd: (0, dd)),
+            pl.BlockSpec((bt, dt), lambda i, j, dd: (i, dd + j)),
+            pl.BlockSpec((bt, dt), lambda i, j, dd: (i, dd + j + 1)),
+        ],
+        out_specs=pl.BlockSpec((bt, kt), lambda i, j, dd: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((nb * bt, nk * kt), jnp.int32),
         interpret=interpret,
-    )(pi_pad, vpad, vpad)
-    return words[:b, :n_words]
+    )(pi_pad.reshape(1, -1), vpad, vpad)
+    sig = out[:b, :k]
+    return sig if pack_b is None else pack_codes(sig, pack_b)

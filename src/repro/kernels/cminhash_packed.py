@@ -19,9 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .packfmt import pack_block, pack_geometry
+from .packfmt import pack_codes
 
 Array = jax.Array
 SENTINEL = jnp.iinfo(jnp.int32).max
@@ -45,16 +44,13 @@ def pack_bits(v: Array) -> Array:
         [bits[:, j::32] << jnp.uint32(j) for j in range(32)])
 
 
-def _kernel(pi_ref, wlo_ref, whi_ref, out_ref, acc_scratch=None, *, bt: int,
-            dt: int, off: int, nd: int = 0, k: int = 0,
-            pack_b: int | None = None):
+def _kernel(pi_ref, wlo_ref, whi_ref, out_ref, *, bt: int, dt: int,
+            off: int):
     d_idx = pl.program_id(2)
-    # see cminhash_kernel._kernel: fused pack accumulates in VMEM scratch
-    acc_ref = out_ref if pack_b is None else acc_scratch
 
     @pl.when(d_idx == 0)
     def _init():
-        acc_ref[...] = jnp.full(acc_ref.shape, SENTINEL, acc_ref.dtype)
+        out_ref[...] = jnp.full(out_ref.shape, SENTINEL, out_ref.dtype)
 
     words = jnp.concatenate([wlo_ref[...], whi_ref[...]], axis=1)  # (Bt, 2*Dt/32)
     pvals = pi_ref[...]                                            # (Dt,) int32
@@ -76,15 +72,7 @@ def _kernel(pi_ref, wlo_ref, whi_ref, out_ref, acc_scratch=None, *, bt: int,
         masked = jnp.where(mask, pvals[None, :], SENTINEL)
         return acc.at[:, k_local].min(jnp.min(masked, axis=1))
 
-    acc_ref[...] = jax.lax.fori_loop(0, dt, body, acc_ref[...])
-
-    if pack_b is not None:
-        # fused sign->pack epilogue (see cminhash_kernel._kernel)
-        col0 = pl.program_id(1) * dt
-
-        @pl.when(d_idx == nd - 1)
-        def _pack():
-            out_ref[...] = pack_block(acc_ref[...], col0, k=k, b=pack_b)
+    out_ref[...] = jax.lax.fori_loop(0, dt, body, out_ref[...])
 
 
 @functools.partial(
@@ -99,7 +87,14 @@ def cminhash_packed_pallas(v: Array, pi: Array, k: int, *,
     """Signatures from a dense binary (B, D) via the bit-packed kernel.
 
     With ``pack_b`` set, returns (B, ceil(K / (32/pack_b))) uint32 packed
-    words from the fused truncate+pack epilogue instead of (B, K) int32.
+    words (``packfmt.pack_codes`` inside the same jit) instead of (B, K)
+    int32.
+
+    Interpret mode only for now: its (Bt, Dt/32) word blocks are narrower
+    than the 128-lane TPU tiling (aligned blocks would force Dt >= 4096 and
+    with it Kt = Dt hash columns per block), and the in-kernel word window
+    is a value-level dynamic slice, which Mosaic does not lower.
+    ``kernels.dispatch`` never selects it on TPU.
     """
     if shift_offset not in (0, 1):
         raise ValueError("shift_offset must be 0 or 1")
@@ -135,22 +130,10 @@ def cminhash_packed_pallas(v: Array, pi: Array, k: int, *,
     sig_spec = pl.BlockSpec((bt, kt), lambda i, j, dd: (i, j))
     sig_shape = jax.ShapeDtypeStruct((nb * bt, nk * kt), jnp.int32)
 
-    if pack_b is None:
-        out = pl.pallas_call(
-            functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset),
-            grid=grid, in_specs=in_specs, out_specs=sig_spec,
-            out_shape=sig_shape, interpret=interpret,
-        )(pi_pad, words, words)
-        return out[:b, :k]
-
-    cpw, n_words = pack_geometry(k, pack_b)  # kt % cpw == 0: kt % 32 == 0
-    owords = pl.pallas_call(
-        functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset, nd=nd,
-                          k=k, pack_b=pack_b),
-        grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, kt // cpw), lambda i, j, dd: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nb * bt, nk * kt // cpw), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((bt, kt), jnp.int32)],
-        interpret=interpret,
+    out = pl.pallas_call(
+        functools.partial(_kernel, bt=bt, dt=dt, off=shift_offset),
+        grid=grid, in_specs=in_specs, out_specs=sig_spec,
+        out_shape=sig_shape, interpret=interpret,
     )(pi_pad, words, words)
-    return owords[:b, :n_words]
+    sig = out[:b, :k]
+    return sig if pack_b is None else pack_codes(sig, pack_b)

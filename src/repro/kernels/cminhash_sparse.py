@@ -21,16 +21,14 @@ Two implementations of the same scan share the precompute helpers:
 * ``cminhash_sparse_windows`` — pure compiled jnp (vmapped dynamic slices);
   the dispatchable fast path on CPU and the oracle-equivalent of the kernel.
 * ``cminhash_sparse_pallas`` — the Pallas kernel: grid over (batch tiles,
-  nnz tiles), window table resident in VMEM, fori_loop of per-row dynamic
-  slices min-folded into the output block.  On TPU the window length is
-  padded to the 128-lane geometry; ``interpret=True`` runs it on CPU.
+  nnz tiles), window table resident in VMEM as 128-lane rows, starts in
+  SMEM, each window read as two ref loads plus a lane rotation and
+  min-folded into the output block.  The window length is padded to the
+  128-lane geometry; ``interpret=True`` runs it on CPU.
 
 Both are bit-identical to the gather path (same exact integer mins), and both
-take the same ``pack_b`` fused sign->pack epilogue as the dense kernels: the
-Pallas kernel accumulates mins in VMEM scratch and packs b-bit words on the
-last nnz tile (``packfmt.pack_block``), the jnp twin folds ``pack_codes``
-into the same compiled scan — either way no (B, K) int32 crosses back as a
-separate device step.
+take ``pack_b``: the b-bit truncate+pack (``packfmt.pack_codes``) runs inside
+the same jit as the scan, so no (B, K) int32 crosses back to the host.
 """
 
 from __future__ import annotations
@@ -42,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .packfmt import pack_block, pack_codes, pack_geometry
+from .packfmt import pack_codes
 
 Array = jax.Array
 SENTINEL = jnp.iinfo(jnp.int32).max
@@ -150,32 +148,29 @@ def cminhash_sparse_windows(idx: Array, pi: Array, k: int,
     return out if pack_b is None else pack_codes(out, pack_b)
 
 
-def _kernel(table_ref, s_ref, out_ref, acc_scratch=None, *, bt: int, jt: int,
-            wl: int, nj: int = 0, k: int = 0, pack_b: int | None = None):
-    # fused pack accumulates mins in VMEM scratch, packing on the last tile
-    # (see cminhash_packed._kernel — same epilogue contract)
-    acc_ref = out_ref if pack_b is None else acc_scratch
+_LANES = 128
 
+
+def _kernel(table_ref, s_ref, out_ref, *, bt: int, jt: int, nr: int):
+    """One (batch tile, nnz tile) step.  The table is (T, 128) lane rows;
+    the window at flat start s = 128*r + c is rows r..r+nr rotated left by
+    c lanes, each lane taking row i or row i+1 by whether it wrapped."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_ref[...] = jnp.full(acc_ref.shape, SENTINEL, acc_ref.dtype)
+        out_ref[...] = jnp.full(out_ref.shape, SENTINEL, out_ref.dtype)
 
-    table = table_ref[...]                            # (L,) int32
-    sv = s_ref[...]                                   # (bt, jt) int32
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nr, _LANES), 1)
+    for bl in range(bt):                              # static: one doc each
+        def body(jl, acc, bl=bl):
+            s = s_ref[bl, jl]                         # scalar from SMEM
+            r = s // _LANES
+            c = s - r * _LANES
+            shift = (_LANES - c) % _LANES             # roll left by c
+            lo = pltpu.roll(table_ref[pl.ds(r, nr), :], shift, 1)
+            hi = pltpu.roll(table_ref[pl.ds(r + 1, nr), :], shift, 1)
+            return jnp.minimum(acc, jnp.where(lane < _LANES - c, lo, hi))
 
-    def body(jl, acc):
-        col = jax.lax.dynamic_slice(sv, (0, jl), (bt, 1))[:, 0]
-        win = jnp.stack([
-            jax.lax.dynamic_slice(table, (col[bl],), (wl,))
-            for bl in range(bt)])                     # (bt, wl)
-        return jnp.minimum(acc, win)
-
-    acc_ref[...] = jax.lax.fori_loop(0, jt, body, acc_ref[...])
-
-    if pack_b is not None:
-        @pl.when(pl.program_id(1) == nj - 1)
-        def _pack():
-            out_ref[...] = pack_block(acc_ref[...], 0, k=k, b=pack_b)
+        out_ref[bl] = jax.lax.fori_loop(0, jt, body, out_ref[bl])
 
 
 @functools.partial(
@@ -185,20 +180,22 @@ def _kernel(table_ref, s_ref, out_ref, acc_scratch=None, *, bt: int, jt: int,
 )
 def cminhash_sparse_pallas(idx: Array, pi: Array, k: int, *,
                            shift_offset: int = 1, block_b: int = 8,
-                           block_j: int = 32, interpret: bool = True,
+                           block_j: int = 128, interpret: bool = True,
                            pack_b: int | None = None) -> Array:
     """Sparse C-MinHash signatures via the tiled Pallas window-min kernel.
 
     idx: (B, NNZ) padded index lists (entries < 0 are padding), already
     sigma-permuted by the caller; pi: (D,) int32.  Returns (B, K) int32, or
-    (B, ceil(K/(32/pack_b))) uint32 words from the fused truncate+pack
-    epilogue when ``pack_b`` is set.
+    (B, ceil(K/(32/pack_b))) uint32 packed words when ``pack_b`` is set
+    (``pack_codes`` on the kernel's mins, inside the same jit).
 
-    Tiling: grid (batch tiles, nnz tiles); the window table is one
-    VMEM-resident block (D + 2*Kp words — ~0.5 MB at D = 65536, K = 1024), so
-    the only HBM traffic per tile is the (Bt, Jt) start block and the output
-    min-accumulation; all K circulant shifts come from that single resident
-    table.  Window length is padded to the 128-lane geometry.
+    Tiling: grid (batch tiles, nnz tiles).  The window table is one
+    VMEM-resident (T, 128) block (D + 2*Kp words — ~0.5 MB at D = 65536,
+    K = 1024) and the window starts ride in SMEM, so the only HBM traffic
+    per tile is the (Bt, Jt) start block and the (Bt, Kp/128, 128) output
+    min-accumulation.  A window is read with two sublane-offset ref loads
+    and one lane rotation — no value-level dynamic slice, which Mosaic does
+    not lower.  Window length Kp is K padded to the 128-lane geometry.
     """
     if shift_offset not in (0, 1):
         raise ValueError("shift_offset must be 0 or 1")
@@ -207,42 +204,32 @@ def cminhash_sparse_pallas(idx: Array, pi: Array, k: int, *,
     b, nnz = idx.shape
     bt = max(1, block_b)
     jt = max(1, block_j)
-    wl = -(-k // 128) * 128                           # lane-padded window
+    wl = -(-k // _LANES) * _LANES                     # lane-padded window
+    nr = wl // _LANES
     nb, nj = -(-b // bt), -(-nnz // jt)
 
+    # one spare lane row past the last window so row r + nr always exists
     table = window_table(pi, wl)
-    lp = -(-table.shape[0] // 128) * 128
-    if lp != table.shape[0]:                          # lane-pad; values unread
-        table = jnp.pad(table, (0, lp - table.shape[0]),
-                        constant_values=SENTINEL)
+    rows = -(-table.shape[0] // _LANES) + 1
+    table = jnp.pad(table, (0, rows * _LANES - table.shape[0]),
+                    constant_values=SENTINEL).reshape(rows, _LANES)
 
     s0 = invalid_start(d, wl)
     s = jnp.full((nb * bt, nj * jt), s0, jnp.int32)
     s = s.at[:b, :nnz].set(window_starts(idx, d, wl,
                                          shift_offset=shift_offset))
 
-    in_specs = [
-        pl.BlockSpec((lp,), lambda i, j: (0,)),
-        pl.BlockSpec((bt, jt), lambda i, j: (i, j)),
-    ]
-    if pack_b is None:
-        out = pl.pallas_call(
-            functools.partial(_kernel, bt=bt, jt=jt, wl=wl),
-            grid=(nb, nj), in_specs=in_specs,
-            out_specs=pl.BlockSpec((bt, wl), lambda i, j: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((nb * bt, wl), jnp.int32),
-            interpret=interpret,
-        )(table, s)
-        return out[:b, :k]
-
-    cpw, n_words = pack_geometry(k, pack_b)   # wl % cpw == 0: wl % 128 == 0
-    owords = pl.pallas_call(
-        functools.partial(_kernel, bt=bt, jt=jt, wl=wl, nj=nj, k=k,
-                          pack_b=pack_b),
-        grid=(nb, nj), in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, wl // cpw), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb * bt, wl // cpw), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((bt, wl), jnp.int32)],
+    out = pl.pallas_call(
+        functools.partial(_kernel, bt=bt, jt=jt, nr=nr),
+        grid=(nb, nj),
+        in_specs=[
+            pl.BlockSpec((rows, _LANES), lambda i, j: (0, 0)),
+            pl.BlockSpec((bt, jt), lambda i, j: (i, j),
+                         memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((bt, nr, _LANES), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * bt, nr, _LANES), jnp.int32),
         interpret=interpret,
     )(table, s)
-    return owords[:b, :n_words]
+    sig = out.reshape(nb * bt, wl)[:b, :k]
+    return sig if pack_b is None else pack_codes(sig, pack_b)

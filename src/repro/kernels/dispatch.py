@@ -5,9 +5,8 @@ is routed to one of the implementations by shape and backend:
 
 dense (B, D) binary:
   * ``int8``    — kernels.cminhash_kernel (int8 circulant bands in VMEM)
-  * ``packed``  — kernels.cminhash_packed (uint32 bit-packed bands: 8x less
-                  HBM per band; wins once the band stream dominates, i.e.
-                  large D on a real accelerator)
+  * ``packed``  — kernels.cminhash_packed (uint32 bit-packed bands; interpret
+                  mode only — its word blocks do not tile the TPU lanes)
   * ``ref``     — kernels.ref jnp oracle (also the fastest dense path on CPU,
                   where Pallas runs in interpret mode)
 sparse (B, NNZ) padded index lists:
@@ -16,39 +15,35 @@ sparse (B, NNZ) padded index lists:
   * ``gather``  — core.cminhash.cminhash_sparse O(B*nnz*K) gather loop
                   (the economical oracle; what ``use_kernel=False`` selects)
 
-``impl="auto"`` policy: on TPU, dense picks ``packed`` when the band stream
-is large enough to be HBM-bound (D >= PACKED_MIN_D) else ``int8``; sparse
-picks ``pallas``.  On CPU (no real accelerator) the compiled-jnp twins win:
-dense ``ref``, sparse ``windows``.  ``use_kernel=False`` always forces the
-reference formulation (``ref``/``gather``).
+``impl="auto"`` policy: on TPU, dense picks ``int8`` and sparse picks
+``pallas``.  On CPU (no real accelerator) the compiled-jnp twins win: dense
+``ref``, sparse ``windows``.  ``use_kernel=False`` always forces the
+reference formulation (``ref``/``gather``).  Kernels that the TPU compiler
+cannot take at served sizes are never picked on TPU, each by a rule below
+that names the reason (``packed`` signing, the Pallas LSH probe).
 
 Block sizes left as ``None`` are resolved through the autotuner
 (``autotune.recommend``: cached winner else heuristic; pass
 ``autotune_measure=True`` to sweep-and-cache on first miss).
 
-``pack_b`` fuses the b-bit truncate+pack epilogue into the dense kernels AND
-the sparse window-min kernels (packed words come straight off the kernel /
-the compiled scan); only the gather oracle still packs as a separate step.
-No shape gate is needed on the fused epilogue: off-TPU the resolved impls
-(``ref``/``windows``) have no in-kernel epilogue — ``pack_b`` there is the
-same ``pack_codes`` call the two-step form makes, so the two forms dispatch
-identical work (an early benchmark artifact recording fused ~10% slower at
-B8/D4096/K256 was non-interleaved timing on a shared box; interleaved
-min-of-N shows them equal — see bench_sign.py).  On TPU the epilogue packs
-from VMEM scratch it already holds, which is never worse than a second
-HBM round trip.
+``pack_b`` returns b-bit packed words: every signing impl packs its
+(B, K) int32 mins with ``packfmt.pack_codes`` inside the same jit, so no
+(B, K) int32 batch reaches the host.
 
 ``lsh_probe`` is the serving-side twin of the signing front door: the LSH
 bucket-probe leg of a query batch, run on device over the table's resident
 fused records (``kernels.lsh_probe``: Pallas kernel + compiled-jnp twin).
-``impl="auto"`` picks the Pallas kernel on TPU and defers to the numpy host
-loop otherwise (the CPU-tuned early-terminating walk in store/table.py).
+``impl="auto"`` picks the compiled-jnp twin on TPU (see
+``select_probe_impl`` for why not the Pallas kernel) and defers to the numpy
+host loop otherwise (the CPU-tuned early-terminating walk in
+store/table.py).
 
 ``query_fused`` is the device-resident query pipeline: uint32-lane band-hash
 fold (``kernels.query_fused``, two planes, bit-identical to the host uint64
 fold) -> probe meta -> ``lsh_probe`` -> packed-code top-k scoring, one
 dispatch entry with no host round trip between stages.  ``impl="auto"``
-picks the Pallas legs on TPU and the compiled-jnp twins elsewhere; the
+picks the Pallas fold on TPU (its probe leg follows ``select_probe_impl``)
+and the compiled-jnp twins elsewhere; the
 legacy host fold + planner walk stays available as the reference oracle
 (``impl="host"`` is the *store's* decision — this front door serves device
 impls only, mirroring ``lsh_probe``).
@@ -72,10 +67,6 @@ from .cminhash_sparse import cminhash_sparse_pallas, cminhash_sparse_windows
 
 Array = jax.Array
 
-# below this universe size the packed kernel's 8x band-stream saving cannot
-# beat its funnel-shift overhead (see kernels/README.md napkin math)
-PACKED_MIN_D = 16384
-
 DENSE_IMPLS = ("auto", "int8", "packed", "ref")
 SPARSE_IMPLS = ("auto", "pallas", "windows", "gather")
 PROBE_IMPLS = ("auto", "numpy", "jnp", "pallas")
@@ -98,7 +89,10 @@ def select_dense_impl(d: int, *, use_kernel: bool = True,
     backend = backend or _backend()
     if backend != "tpu":
         return "ref"        # compiled jnp beats interpret-mode Pallas on CPU
-    return "packed" if d >= PACKED_MIN_D else "int8"
+    # never "packed" on TPU: its (Bt, Dt/32) word blocks are narrower than
+    # the 128-lane tiling and its word window is a value-level dynamic
+    # slice — the TPU compiler refuses both (cminhash_packed docstring)
+    return "int8"
 
 
 def select_sparse_impl(*, use_kernel: bool = True,
@@ -149,10 +143,6 @@ def signatures_dense(v: Array, pi: Array, k: int, sigma: Array | None = None,
     blocks = _resolve_blocks(kind, b, d, k,
                              {"block_b": block_b, "block_d": block_d},
                              autotune_measure)
-    if pack_b is not None:
-        cpw = 32 // pack_b
-        if blocks["block_d"] % cpw:    # keep word boundaries on block edges
-            blocks["block_d"] = -(-blocks["block_d"] // cpw) * cpw
     kernel = cminhash_pallas if impl == "int8" else cminhash_packed_pallas
     return kernel(v, pi, k, shift_offset=shift_offset,
                   interpret=_interpret(), pack_b=pack_b, **blocks)
@@ -197,12 +187,18 @@ def signatures_sparse(idx: Array, pi: Array, k: int,
 # -- LSH bucket probe (the serving-side device leg) ---------------------------
 
 def select_probe_impl(backend: str | None = None) -> str:
-    """Resolve impl="auto" for a bucket-probe request: the Pallas kernel on
-    a real accelerator, the numpy host loop otherwise (interpret-mode Pallas
+    """Resolve impl="auto" for a bucket-probe request: the compiled-jnp
+    device twin on TPU, the numpy host loop otherwise (interpret-mode Pallas
     and the jnp twin both lose to the cache-tuned early-terminating walk on
-    CPU)."""
+    CPU).
+
+    Never the Pallas kernel on TPU: it holds the whole fused records table
+    as one VMEM block, 4 * n_bands * n_slots * (2 + W) bytes — gigabytes at
+    served sizes (2^21 slots x 32 bands x 10 words is 2.7 GB) against
+    tens of MB of VMEM.  Serving the probe from a Pallas kernel needs a
+    DMA-gather redesign that leaves the records in HBM."""
     backend = backend or _backend()
-    return "pallas" if backend == "tpu" else "numpy"
+    return "jnp" if backend == "tpu" else "numpy"
 
 
 def lsh_probe(records_dev: Array, hashes: np.ndarray, *, n_slots: int,
@@ -222,7 +218,7 @@ def lsh_probe(records_dev: Array, hashes: np.ndarray, *, n_slots: int,
     if impl not in PROBE_IMPLS:
         raise ValueError(f"impl must be one of {PROBE_IMPLS} (got {impl!r})")
     if impl == "auto":
-        impl = "pallas" if _backend() == "tpu" else "jnp"
+        impl = "jnp"            # the device twin on every backend (above)
     obs_metrics.default().counter(f"kernel.probe.{impl}").inc()
     if impl == "numpy":
         raise ValueError("impl='numpy' is BandedLSHTable.lookup's own host "
@@ -322,12 +318,16 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
     if impl == "host":
         raise ValueError("impl='host' is the store's legacy fold + planner "
                          "walk; call the store, not the dispatch layer")
-    obs_metrics.default().counter(f"kernel.query_fused.{impl}").inc()
-    qwords = jnp.asarray(qwords)
+    reg = obs_metrics.default()
+    reg.counter(f"kernel.query_fused.{impl}").inc()
+    # the query batch follows the store state to its device (a shard of the
+    # in-process plane may live on any device of the host)
+    qwords = jax.device_put(jnp.asarray(qwords), words_dev.sharding)
     q = qwords.shape[0]
     w = records_dev.shape[1] - 2
 
     if hashes is None:
+        reg.counter(f"kernel.fold.{impl}").inc()
         rows_hi, rows_lo = _query_fused.words_to_planes(qwords, n_bands)
         hi, lo = _fold_planes(rows_hi, rows_lo, impl=impl, block_q=block_q,
                               autotune_measure=autotune_measure)
@@ -338,12 +338,16 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
     else:
         meta = jnp.asarray(_lsh_probe.probe_operands(hashes, n_slots))
 
-    if impl == "pallas":
+    # the probe leg takes the Pallas kernel only off TPU (interpret mode):
+    # on TPU its VMEM-resident records cannot fit (select_probe_impl)
+    probe = "pallas" if impl == "pallas" and _backend() != "tpu" else "jnp"
+    reg.counter(f"kernel.probe.{probe}").inc()
+    if probe == "pallas":
         blocks = _resolve_blocks("probe_pallas", meta.shape[0], n_slots, w,
                                  {"block_e": block_e}, autotune_measure)
         cand = _lsh_probe.lsh_probe_pallas(records_dev, meta, n_slots=n_slots,
                                            max_probes=max_probes,
-                                           interpret=_interpret(), **blocks)
+                                           interpret=True, **blocks)
     else:
         cand = _lsh_probe.lsh_probe_jnp(records_dev, meta, n_slots=n_slots,
                                         max_probes=max_probes)
@@ -353,6 +357,7 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
         if spill.size:
             cand = jnp.concatenate(
                 [cand, jnp.asarray(spill.astype(np.int32))], axis=1)
+    reg.counter("kernel.score.jnp").inc()
     ids, scores, has = _query_fused.score_topk(cand, words_dev, qwords,
                                                k=k, b=b, top_k=top_k)
     return (np.asarray(ids).astype(np.int64), np.asarray(scores),

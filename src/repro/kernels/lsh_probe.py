@@ -23,8 +23,8 @@ reduces each entry to five int32s (band offset, base slot, key halves,
 validity) and everything after that is device work:
 
 * ``lsh_probe_jnp``    — compiled-jnp twin: one (E, 2+W) gather per probe
-  depth, hit-select folded across depths.  The dispatchable device path on
-  CPU-hosted backends and the oracle-equivalent of the kernel.
+  depth, hit-select folded across depths.  The device probe on every
+  backend, TPU included, and the oracle-equivalent of the kernel.
 * ``lsh_probe_pallas`` — Pallas kernel: grid over query-entry tiles,
   records block resident in VMEM, fori_loop of per-entry dynamic slices
   with a statically unrolled probe chain.  ``interpret=True`` runs on CPU.
@@ -140,9 +140,11 @@ def lsh_probe_pallas(flat_records: Array, meta: Array, *, n_slots: int,
     """Pallas probe kernel: (E, 5) operands -> (E, W) candidate ids, -1 pad.
 
     Grid over entry tiles of ``block_e``; the records block is VMEM-resident
-    across the whole grid (4 * n_bands * n_slots * (2 + W) bytes — size the
-    table's geometry accordingly on real accelerators), so per-tile HBM
-    traffic is just the operand block and the output rows.
+    across the whole grid (4 * n_bands * n_slots * (2 + W) bytes), so
+    per-tile HBM traffic is just the operand block and the output rows.
+    Interpret mode only: at served sizes that block is gigabytes, and the
+    per-entry value-level dynamic slices do not lower on TPU either, so
+    ``kernels.dispatch`` never selects this kernel there.
     """
     e, mc = meta.shape
     r, rw = flat_records.shape

@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as obs_metrics
 from . import dispatch, ref
 from .collision_kernel import collision_count_pallas
 from .packfmt import (PACK_BITS, pack_codes,  # noqa: F401  (re-exports)
@@ -56,9 +57,12 @@ def cminhash_signatures_packed(v: Array, pi: Array, k: int, b: int,
 
 
 def collision_counts(sig_q: Array, sig_n: Array, *, use_kernel: bool = True,
-                     block_q: int = 64, block_n: int = 64,
+                     block_q: int = 64, block_n: int = 128,
                      block_k: int = 128) -> Array:
-    """(Q, K) x (N, K) -> (Q, N) int32 match counts via kernel or oracle."""
+    """(Q, K) x (N, K) -> (Q, N) int32 match counts via kernel or oracle.
+    The default blocks tile the TPU's (8, 128) layout."""
+    obs_metrics.default().counter(
+        f"kernel.collision.{'pallas' if use_kernel else 'ref'}").inc()
     if use_kernel:
         return collision_count_pallas(sig_q, sig_n, block_q=block_q,
                                       block_n=block_n, block_k=block_k,

@@ -5,10 +5,9 @@ words: code j of a row lives at bit (j % (32/b)) * b of word j // (32/b).
 b == 32 is a bitcast (one code per word, codes == signatures), so scoring on
 packed words at b = 32 is bit-exact with scoring the raw signatures.
 
-This module is a leaf (jax-only imports) so both the host-side packers
-(``pack_codes``/``unpack_codes``) and the in-kernel fused epilogue
-(``pack_block``) share the exact same geometry — the fused sign->pack path in
-the dense Pallas kernels is asserted bit-identical to sign-then-``pack_codes``.
+This module is a leaf (jax-only imports): the host-side packers, the
+signing kernels' ``pack_b`` option and the scorers all share the one
+geometry defined here.
 """
 
 from __future__ import annotations
@@ -58,29 +57,3 @@ def unpack_codes(words: Array, k: int, b: int) -> Array:
     mask = jnp.uint32((1 << b) - 1)
     codes = (words[:, :, None] >> shifts) & mask
     return codes.reshape(bsz, n_words * cpw)[:, :k].astype(jnp.int32)
-
-
-def pack_block(acc: Array, col0, *, k: int, b: int) -> Array:
-    """In-kernel fused epilogue: (Bt, Kt) int32 mins -> (Bt, Kt*b/32) words.
-
-    ``col0`` is the global hash column of ``acc[:, 0]`` (may be traced);
-    columns at global index >= k are zeroed to match ``pack_codes`` padding.
-    Kt must be a multiple of 32/b.  Uses a static bitwise-OR fold — no
-    (Bt, W, cpw) sum intermediate — safe inside Pallas (2D iota only).
-    """
-    bt, kt = acc.shape
-    cpw, _ = pack_geometry(kt, b)  # validates b; kt stands in for k here
-    if kt % cpw:
-        raise ValueError(f"block K width {kt} not a multiple of {cpw}")
-    col = jax.lax.broadcasted_iota(jnp.int32, (bt, kt), 1) + col0
-    if b == 32:
-        codes = jax.lax.bitcast_convert_type(acc, jnp.uint32)
-    else:
-        codes = (acc & ((1 << b) - 1)).astype(jnp.uint32)
-    codes = jnp.where(col < k, codes, jnp.uint32(0))
-    if cpw == 1:
-        return codes
-    grp = codes.reshape(bt, kt // cpw, cpw)
-    return functools.reduce(
-        jnp.bitwise_or,
-        [grp[:, :, i] << jnp.uint32(i * b) for i in range(cpw)])

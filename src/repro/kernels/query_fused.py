@@ -13,7 +13,7 @@ accelerator from packed words to ranked (id, score) rows:
   ``h ^= h >> 29`` cross-plane shift.  Bit-identical to the host fold for
   every input — including negative int32 signature codes, whose host-side
   ``astype(np.uint64)`` sign-extends (the ``hi`` plane is all-ones there).
-  Both a Pallas kernel (``fold_planes_pallas``, grid over batch tiles) and
+  Both a Pallas kernel (``fold_planes_pallas``, lane-dense entry tiles) and
   a compiled-jnp twin (``fold_planes_jnp``) are provided; parity is swept
   in tests/test_query_fused.py.
 * **device probe meta** — ``meta_from_planes`` builds the ``lsh_probe``
@@ -154,46 +154,49 @@ def fold_planes_jnp(rows_hi: Array, rows_lo: Array) -> tuple[Array, Array]:
     return _fold_planes(rows_hi, rows_lo)
 
 
-def _fold_kernel(hi_ref, lo_ref, out_hi_ref, out_lo_ref):
-    hi, lo = _fold_planes(hi_ref[...], lo_ref[...])
+def _fold_kernel(hi_ref, lo_ref, out_hi_ref, out_lo_ref, *, r: int):
+    hi = jnp.zeros(out_lo_ref.shape, jnp.uint32)
+    lo = jnp.zeros_like(hi)
+    for i in range(r):                       # static: one round per row
+        hi, lo = _fold_step(hi, lo, hi_ref[i], lo_ref[i])
     out_hi_ref[...] = hi
     out_lo_ref[...] = lo
+
+
+_TILE = 8 * 128                              # one (8, 128) uint32 vreg tile
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def fold_planes_pallas(rows_hi: Array, rows_lo: Array, *, block_q: int = 128,
                        interpret: bool = True) -> tuple[Array, Array]:
-    """Pallas fold kernel: grid over batch tiles of ``block_q`` queries.
+    """Pallas fold kernel: (B, nb, R) uint32 planes -> (B, nb) hi/lo planes.
 
-    Each tile folds its (block_q, nb, R) planes fully in VMEM — the R
-    rounds are statically unrolled, so per-tile HBM traffic is one read of
-    the input planes and one write of the (block_q, nb) key planes.
-    ``interpret=True`` runs on CPU."""
+    Every (query, band) entry folds independently, so the kernel sees the
+    flat entries lane-dense: the planes go in as (R, E/128, 128) and each
+    grid step folds one tile of ``block_q * nb`` entries (rounded up to
+    whole (8, 128) tiles) with the R rounds statically unrolled.  Per-tile
+    HBM traffic is one read of the input planes and one write of the key
+    planes.  ``interpret=True`` runs on CPU."""
     q, nb, r = rows_lo.shape
-    qt = max(1, block_q)
-    nq = -(-q // qt)
-    if nq * qt != q:
-        pad = ((0, nq * qt - q), (0, 0), (0, 0))
-        rows_hi = jnp.pad(rows_hi, pad)
-        rows_lo = jnp.pad(rows_lo, pad)
+    e = q * nb
+    te = -(-max(1, block_q) * nb // _TILE) * _TILE
+    ne = -(-e // te)
+
+    def lanes(x):                            # (B, nb, R) -> (R, E'/128, 128)
+        x = jnp.pad(x.reshape(e, r).T, ((0, 0), (0, ne * te - e)))
+        return x.reshape(r, ne * te // 128, 128)
+
+    rows = te // 128
     out_hi, out_lo = pl.pallas_call(
-        _fold_kernel,
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((qt, nb, r), lambda i: (i, 0, 0)),
-            pl.BlockSpec((qt, nb, r), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((qt, nb), lambda i: (i, 0)),
-            pl.BlockSpec((qt, nb), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq * qt, nb), jnp.uint32),
-            jax.ShapeDtypeStruct((nq * qt, nb), jnp.uint32),
-        ],
+        functools.partial(_fold_kernel, r=r),
+        grid=(ne,),
+        in_specs=[pl.BlockSpec((r, rows, 128), lambda i: (0, i, 0))] * 2,
+        out_specs=[pl.BlockSpec((rows, 128), lambda i: (i, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((ne * rows, 128), jnp.uint32)] * 2,
         interpret=interpret,
-    )(rows_hi, rows_lo)
-    return out_hi[:q], out_lo[:q]
+    )(lanes(rows_hi), lanes(rows_lo))
+    return (out_hi.reshape(-1)[:e].reshape(q, nb),
+            out_lo.reshape(-1)[:e].reshape(q, nb))
 
 
 def planes_to_hashes(hi, lo) -> np.ndarray:

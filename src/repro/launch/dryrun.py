@@ -136,8 +136,6 @@ def analyze_cell(arch: str, shape_name: str, mesh, mesh_name: str,
             "code_bytes": mem.generated_code_size_in_bytes,
         }
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):
-            ca = ca[0]
         rec["xla_cost"] = {k: ca[k] for k in ("flops", "bytes accessed")
                            if k in ca}
         txt = compiled.as_text()

@@ -7,10 +7,7 @@ import jax
 
 
 def _axis_types(n: int) -> dict:
-    # jax < 0.4.35 has no sharding.AxisType; Auto is its only behavior there
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

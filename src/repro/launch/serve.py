@@ -226,6 +226,8 @@ def main() -> None:
                     help="probability a query batch opens a (cross-process) "
                          "trace; 0 disables tracing (search mode)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.mode == "lm":
         serve_lm(args)
     else:

@@ -130,11 +130,7 @@ def moe_block(p: dict, x: Array, cfg, mesh=None) -> tuple[Array, Array]:
             aux = jax.lax.psum(aux, tuple(mesh.axis_names)) / n_mesh
             return y, aux
 
-        if hasattr(jax, "shard_map"):
-            shard_map = jax.shard_map
-        else:                          # jax < 0.4.35 spells it experimental
-            from jax.experimental.shard_map import shard_map
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(batch_axes, "model"), P(None, None),
                       P("model", None, None), P("model", None, None),

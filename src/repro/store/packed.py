@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,6 +66,7 @@ class PackedSignatureBuffer:
         # same pattern as BandedLSHTable.device_records
         self._version = 0
         self._device: tuple[int, jnp.ndarray] | None = None
+        self.device = None      # where device_words lives (None: default)
 
     # -- sizing ------------------------------------------------------------
     @property
@@ -129,9 +131,12 @@ class PackedSignatureBuffer:
     def device_words(self) -> jnp.ndarray:
         """(size, W) packed rows resident on device, re-uploaded only after
         a mutation (the fused query path scores every query batch against
-        this one cached copy instead of gathering + staging per call)."""
+        this one cached copy instead of gathering + staging per call).  It
+        lives on ``self.device`` (the default device when None)."""
         if self._device is None or self._device[0] != self._version:
-            self._device = (self._version, jnp.asarray(self.all_packed()))
+            self._device = None               # free the stale copy first
+            self._device = (self._version,
+                            jax.device_put(self.all_packed(), self.device))
         return self._device[1]
 
     def codes(self, ids) -> jnp.ndarray:

@@ -38,6 +38,11 @@ order, so a shard's local rank order IS its global id order — per-shard
 score-tie breaks (smaller local id first) map to smaller-global-id first,
 which is what makes the merge bit-exact.
 
+Device residency: in-process shard i keeps its packed words and LSH records
+on local device ``i mod n`` (``shard_device``), so on a four-chip host an
+S=4 plane holds each shard's state on its own chip; on one device every
+shard shares it.
+
 ``save``/``load`` snapshot the whole plane to a directory: one
 ``SketchStore`` npz per shard plus a manifest (cfg, n_shards, partition,
 gid maps).  Shard workers boot from the same per-shard files
@@ -84,6 +89,16 @@ def shard_partial_hist_name(shard: int) -> str:
     copy so co-resident planes can't pollute each other's signal); bench
     and ops tooling read the registry histograms by this name."""
     return f"query.shard{shard}.partial"
+
+
+def shard_device(shard: int):
+    """Where in-process shard ``shard`` keeps its device state: device
+    ``shard mod n`` of this host's n local devices, so an S-shard plane on
+    a four-chip host holds each shard's words and records on its own chip.
+    None (the default device) on a one-device host."""
+    import jax
+    devices = jax.local_devices()
+    return devices[shard % len(devices)] if len(devices) > 1 else None
 
 
 # -- the backend seam ---------------------------------------------------------
@@ -147,7 +162,7 @@ class InProcessShard:
     def __init__(self, cfg: StoreConfig | None = None, *,
                  probe_impl: str | None = None,
                  query_impl: str | None = None,
-                 store: SketchStore | None = None):
+                 store: SketchStore | None = None, device=None):
         if store is None:
             if cfg is None:
                 raise ValueError("InProcessShard needs cfg or store")
@@ -158,6 +173,8 @@ class InProcessShard:
                 store.probe_impl = probe_impl
             if query_impl is not None:
                 store.query_impl = query_impl
+        if device is not None:
+            store.place(device)
         self.store = store
 
     def _add(self, fn, batch) -> int:
@@ -245,8 +262,9 @@ class ShardedSketchStore:
         # got their own copy at spawn time — see transport.server)
         self.query_impl = query_impl
         self.shards = backends if backends is not None else [
-            InProcessShard(cfg, probe_impl=probe_impl, query_impl=query_impl)
-            for _ in range(n_shards)]
+            InProcessShard(cfg, probe_impl=probe_impl, query_impl=query_impl,
+                           device=shard_device(i))
+            for i in range(n_shards)]
         # local->global id map per shard (amortized-doubling append buffer)
         self._gid_buf = [np.zeros(8, np.int64) for _ in range(n_shards)]
         self._gid_len = [0] * n_shards
@@ -611,7 +629,7 @@ class ShardedSketchStore:
             backends = [
                 InProcessShard(store=SketchStore.load(
                     shard_snapshot_path(dirpath, i)), probe_impl=probe_impl,
-                    query_impl=query_impl)
+                    query_impl=query_impl, device=shard_device(i))
                 for i in range(n_shards)]
         elif len(backends) != n_shards:
             raise ValueError(f"snapshot has {n_shards} shards, got "

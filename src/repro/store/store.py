@@ -127,11 +127,19 @@ class SketchStore:
                                     bucket_width=cfg.bucket_width,
                                     max_probes=cfg.max_probes)
         self.planner = QueryPlanner(self.buffer)
+        self.place(None)
         self.n_rebuilds = 0
         # at b < 32 sig-keys (band_hashes over raw signatures) and packed
         # keys (band_hashes_packed over truncated words) differ; the first
         # write pins the mode and mixing raises instead of silently missing
         self._band_mode: str | None = None
+
+    def place(self, device) -> None:
+        """Keep the device-resident state (packed words, LSH records) on
+        ``device`` — a ``jax.Device``, or None for the default device."""
+        self.device = device
+        self.buffer.device = device
+        self.table.device = device
 
     # -- sizing ------------------------------------------------------------
     @property
@@ -463,6 +471,7 @@ class SketchStore:
             store.buffer = PackedSignatureBuffer.from_rows(
                 store.buffer.cfg, z["words"])
             store.planner = QueryPlanner(store.buffer)
+            store.place(store.device)
             hashes = z["table_hashes"]
             if len(hashes):
                 store.table.insert(

@@ -58,6 +58,7 @@ class BandedLSHTable:
         self.n_slots = n_slots
         self.bucket_width = bucket_width
         self.max_probes = max_probes
+        self.device = None      # where device_records lives (None: default)
         # registry handles bound once per table; occupancy gauges report
         # DELTAS (new - last reported) so N tables in one process sum to a
         # process total — the same additive semantics gauge merges use
@@ -266,12 +267,15 @@ class BandedLSHTable:
     def device_records(self):
         """(n_bands * n_slots, 2 + W) int32 device copy of the fused records,
         cached by mutation version — the table uploads once per build/rebuild
-        and query batches probe the resident copy (kernels/lsh_probe.py)."""
-        import jax.numpy as jnp       # local: table stays numpy-importable
+        and query batches probe the resident copy (kernels/lsh_probe.py).
+        It lives on ``self.device`` (the default device when None)."""
+        import jax                    # local: table stays numpy-importable
         cached = self._dev_records
         if cached is None or cached[0] != self._records_version:
             flat = self.records.reshape(-1, 2 + self.bucket_width)
-            self._dev_records = (self._records_version, jnp.asarray(flat))
+            self._dev_records = None          # free the stale copy first
+            self._dev_records = (self._records_version,
+                                 jax.device_put(flat, self.device))
         return self._dev_records[1]
 
     def lookup(self, hashes: np.ndarray, *, impl: str = "numpy") -> np.ndarray:

@@ -31,6 +31,15 @@ connection, since the stream can no longer be trusted to be in sync.  EOF
 from the client returns the worker to ``accept`` — a coordinator can
 reconnect.  Only SHUTDOWN (acked first) exits the process.
 
+One process per chip: a coordinator that serves from an accelerator holds
+that chip, and a worker that tried to claim it too would fail on the
+runtime's lock or quietly land on the CPU.  So a worker's JAX platform is
+never left to chance: ``spawn_workers`` pins ``WORKER_PLATFORM`` (the
+host CPU) in each child's environment and JAX config before anything
+compiles, the worker reports it in STATS next to
+``probe_impl``/``query_impl``, and a worker that did not get the platform
+it was asked for fails at boot.
+
 ``spawn_workers(slow_shards=...)`` injects probabilistic latency into a
 worker's QUERY/BRUTE handling (a pre-handle sleep) — the reproducible
 "one slow shard" scenario the hedging benchmarks and CI smoke use to
@@ -49,8 +58,10 @@ import threading
 import time
 import traceback
 
+import jax
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.store.sharded import shard_snapshot_path
@@ -59,6 +70,10 @@ from repro.store.store import SketchStore, StoreConfig
 from . import wire
 from .faults import KILL_EXIT_CODE, FaultPlan
 from .wire import Message, MsgType
+
+# the JAX platform spawned workers run on: the host CPU, since the
+# coordinator process holds the accelerator (one process per chip)
+WORKER_PLATFORM = "cpu"
 
 GATE_LIMIT_ENV = "REPRO_GATE_LIMIT"
 DEFAULT_GATE_LIMIT = 64
@@ -167,6 +182,7 @@ def _handle(store: SketchStore, msg: Message,
                                     "n_rebuilds": store.n_rebuilds,
                                     "probe_impl": store.probe_impl,
                                     "query_impl": store.query_impl,
+                                    "platform": jax.default_backend(),
                                     "pid": os.getpid(),
                                     "shard": int(shard),
                                     "replica": int(replica),
@@ -345,7 +361,8 @@ def run_worker(ready_conn, cfg: StoreConfig | None, snapshot: str | None,
                shard: int = -1, query_impl: str = "auto",
                slow: tuple[float, float] | None = None,
                replica: int = 0, gate_limit: int | None = None,
-               fault_spec: str | None = None) -> None:
+               fault_spec: str | None = None,
+               platform: str = WORKER_PLATFORM) -> None:
     """Worker entry point (spawn target — all arguments picklable).
 
     Boots a ``SketchStore`` (empty from ``cfg``, or from ``snapshot``),
@@ -366,7 +383,12 @@ def run_worker(ready_conn, cfg: StoreConfig | None, snapshot: str | None,
     gate but admits nothing — the always-shed worker the overload tests
     use).  ``fault_spec`` is a ``FaultPlan.encode()`` JSON schedule
     (``REPRO_FAULTS`` env keyed ``"<shard>.<replica>"`` when None).
+
+    ``platform`` is pinned before the first compile (``_pin_platform``):
+    a worker that cannot get it raises here, before reporting an address.
     """
+    _pin_platform(platform)
+    setup_compile_cache()
     lane = f"{shard}.{replica}"
     if fault_spec is not None:
         faults = FaultPlan.decode(fault_spec, lane=lane)
@@ -443,6 +465,19 @@ def run_worker(ready_conn, cfg: StoreConfig | None, snapshot: str | None,
         lsock.close()
 
 
+def _pin_platform(platform: str) -> None:
+    """Run this process's JAX on ``platform`` or fail: set in the
+    environment (inherited by anything this worker starts) and in JAX's
+    config (the environment was read when jax was imported), then checked
+    against the backend JAX actually brings up."""
+    os.environ["JAX_PLATFORMS"] = platform
+    jax.config.update("jax_platforms", platform)
+    got = jax.default_backend()
+    if got != platform:
+        raise RuntimeError(f"shard worker asked for JAX platform "
+                           f"{platform!r} but got {got!r}")
+
+
 class WorkerHandle:
     """A spawned shard worker: its process and its bound address."""
 
@@ -503,6 +538,9 @@ def spawn_workers(cfg: StoreConfig | None, n_workers: int, *,
     default).  ``faults`` maps WORKER index -> ``FaultPlan`` (or its
     ``encode()`` JSON) — the deterministic chaos schedule; workers with no
     entry also pick up ``REPRO_FAULTS`` env keyed by lane.
+
+    Every worker is pinned to ``WORKER_PLATFORM`` (module docstring: one
+    process per chip).
     """
     if shards is None:
         shards = list(range(n_workers))
@@ -525,7 +563,7 @@ def spawn_workers(cfg: StoreConfig | None, n_workers: int, *,
                 args=(child, cfg, snap, probe_impl, host, 0, shards[i],
                       query_impl,
                       slow_shards.get(i) if slow_shards else None,
-                      replicas[i], gate_limit, plan),
+                      replicas[i], gate_limit, plan, WORKER_PLATFORM),
                 daemon=True, name=f"shard-worker-{shards[i]}r{replicas[i]}")
             proc.start()
             child.close()

@@ -149,3 +149,35 @@ def test_engine_autotune_measure_populates_cache():
     eng2 = SketchEngine(SketchConfig(d=256, k=32, seed=0))
     assert np.array_equal(np.asarray(sig), np.asarray(
         eng2.signatures_sparse(idx)))
+
+
+def test_sweep_raises_when_no_candidate_compiles(monkeypatch):
+    """A kernel that compiles at no block size must fail loudly — dropping
+    every candidate and caching nothing would let a broken kernel pass."""
+    def broken_runner(kind, b, d, k, nnz, seed):
+        def thunk_for(blocks):
+            def fn():
+                raise ValueError(f"refused block {blocks}")
+            return fn
+        return thunk_for
+
+    monkeypatch.setattr(autotune, "_make_runner", broken_runner)
+    with pytest.raises(RuntimeError, match="no block candidate compiled"):
+        autotune.measure("sparse_pallas", 64, 1024, 32, warmup=0, iters=1)
+    assert autotune.cached("sparse_pallas", 64, 1024, 32) is None
+
+
+@pytest.mark.parametrize("kind", autotune.KINDS)
+def test_tpu_candidates_tile_the_lanes(kind):
+    """Every TPU sweep candidate and default is (8, 128)-aligned where the
+    block is a sublane/lane dim of a Pallas block."""
+    pool = autotune._candidates_for(kind, "tpu")
+    if kind in ("dense_int8", "sparse_pallas"):
+        pool = pool + (autotune._DEFAULTS[kind],)
+    for blocks in pool:
+        assert blocks.get("block_b", 8) % 8 == 0, (kind, blocks)
+        assert blocks.get("block_d", 128) % 128 == 0, (kind, blocks)
+        if kind == "sparse_pallas":
+            assert blocks["block_j"] % 128 == 0, blocks
+    clamped = autotune.recommend("dense_int8", 64, 100, 16, backend="tpu")
+    assert clamped["block_d"] == 128

@@ -2,7 +2,7 @@
 
 Covers the non-divisible shapes the tiling has to get right — b % block_b,
 d % block_d, k < block_d, k % 32 — for shift_offset in {0, 1}, plus the fused
-sign->pack epilogue (bit-identical to sign-then-pack_codes for every b), the
+sign->pack output (bit-identical to sign-then-pack_codes for every b), the
 engine's config routing, and the packed store ingest path.
 """
 
@@ -96,8 +96,8 @@ def test_fused_pack_bit_identical(B, D, K, dens, b):
                                         **BLOCKS)
         assert got.dtype == jnp.uint32
         assert np.array_equal(np.asarray(got), want), impl
-    # sparse paths: window-min kernels fuse the same epilogue (gather packs
-    # as a separate step but must agree bit-for-bit)
+    # sparse paths: every impl packs inside its jit and must agree
+    # bit-for-bit
     for impl, blocks in (("gather", {}),
                          ("windows", {"block_j": 4}),
                          ("pallas", {"block_b": 2, "block_j": 4})):
@@ -108,13 +108,16 @@ def test_fused_pack_bit_identical(B, D, K, dens, b):
 
 
 def test_auto_policy():
-    # CPU: compiled jnp twins; TPU: kernels, packed once D is HBM-bound
+    # CPU: compiled jnp twins; TPU: the kernels that lower there — int8 at
+    # every D (the packed kernel's blocks do not tile the TPU lanes)
     assert dispatch.select_dense_impl(512, backend="cpu") == "ref"
     assert dispatch.select_dense_impl(512, use_kernel=False,
                                       backend="tpu") == "ref"
     assert dispatch.select_dense_impl(512, backend="tpu") == "int8"
-    assert dispatch.select_dense_impl(dispatch.PACKED_MIN_D,
-                                      backend="tpu") == "packed"
+    assert dispatch.select_dense_impl(1 << 16, backend="tpu") == "int8"
+    # the probe's records never fit VMEM at served sizes: jnp twin on TPU
+    assert dispatch.select_probe_impl(backend="tpu") == "jnp"
+    assert dispatch.select_probe_impl(backend="cpu") == "numpy"
     assert dispatch.select_sparse_impl(backend="cpu") == "windows"
     assert dispatch.select_sparse_impl(backend="tpu") == "pallas"
     assert dispatch.select_sparse_impl(use_kernel=False,

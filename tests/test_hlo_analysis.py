@@ -9,11 +9,6 @@ import pytest
 from repro.analysis import hlo
 
 
-def _cost(compiled) -> dict:
-    ref = compiled.cost_analysis()
-    return ref[0] if isinstance(ref, list) else ref   # older jax wraps it
-
-
 def test_matches_xla_on_scan_free_module():
     def f(x, w):
         return jnp.tanh(x @ w)
@@ -22,7 +17,7 @@ def test_matches_xla_on_scan_free_module():
     w = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     c = jax.jit(f).lower(x, w).compile()
     got = hlo.analyze(c.as_text())
-    ref = _cost(c)
+    ref = c.cost_analysis()
     assert got.flops == pytest.approx(ref["flops"], rel=0.02)
     # the naive model reproduces XLA's every-op accounting
     assert got.bytes_naive == pytest.approx(ref["bytes accessed"], rel=0.1)
@@ -74,7 +69,7 @@ def test_scan_trip_count_multiplies():
         ws = jax.ShapeDtypeStruct((L, 256, 256), jnp.float32)
         c = jax.jit(f).lower(x, ws).compile()
         got = hlo.analyze(c.as_text())
-        ref = _cost(c)
+        ref = c.cost_analysis()
         assert got.flops == pytest.approx(L * ref["flops"], rel=0.05), L
 
 
@@ -116,9 +111,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis import hlo
 
-kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2} \
-    if hasattr(jax.sharding, "AxisType") else {}   # jax < 0.4.35
-mesh = jax.make_mesh((2, 4), ("data", "model"), **kw)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def layer(x, w):
     w1, w2 = w

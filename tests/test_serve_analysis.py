@@ -97,3 +97,12 @@ def test_kernel_roofline_packing_helps_memory_only():
     assert a["ops"] == b["ops"]
     assert b["bytes"] < a["bytes"] / 2
     assert b["arith_intensity"] > a["arith_intensity"]
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    import pytest
+
+    from repro.analysis import roofline
+    assert roofline.peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no peak figures"):
+        roofline.peaks("cpu")

@@ -30,20 +30,6 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 import numpy as np
-
-# jax < 0.4.35 compat: no sharding.AxisType (Auto is the only behavior
-# there) and shard_map still lives under experimental
-if not hasattr(jax.sharding, "AxisType"):
-    class _AxisType:
-        Auto = None
-    jax.sharding.AxisType = _AxisType
-    _real_make_mesh = jax.make_mesh
-    def _make_mesh(shape, axes, axis_types=None, **kw):
-        return _real_make_mesh(shape, axes, **kw)
-    jax.make_mesh = _make_mesh
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-    jax.shard_map = _shard_map
 """
 
 

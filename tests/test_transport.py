@@ -81,6 +81,8 @@ def test_tcp_plane_bit_identical(s, tmp_path):
         for sh in tcp.shards:
             assert sh.stats()["probe_impl"] in ("numpy", "jnp", "pallas")
             assert sh.stats()["query_impl"] in ("jnp", "pallas", "host")
+            # ...on the JAX platform the coordinator pinned at spawn
+            assert sh.stats()["platform"] == "cpu"
         # wall-time split is populated for the artifact row
         assert set(tcp.last_timings) == \
             {"fold_s", "broadcast_s", "partial_s", "merge_s"}
@@ -96,6 +98,17 @@ def test_tcp_plane_bit_identical(s, tmp_path):
     finally:
         for h in handles:
             h.terminate()
+
+
+def test_worker_fails_at_boot_on_wrong_platform(monkeypatch):
+    """A worker that cannot get the JAX platform it was spawned for dies
+    before reporting an address — it never serves from some other backend
+    in silence."""
+    from repro.transport import server
+    monkeypatch.setattr(server, "WORKER_PLATFORM", "no_such_platform")
+    cfg = StoreConfig(k=K, n_bands=NB, rows_per_band=R)
+    with pytest.raises(RuntimeError, match="shard worker 0"):
+        spawn_workers(cfg, 1, start_timeout=60)
 
 
 def test_tcp_packed_path_and_snapshot_boot(tmp_path):
