@@ -59,6 +59,7 @@ import numpy as np
 from ..core import cminhash
 from ..core.permutations import apply_permutation_dense, apply_permutation_sparse
 from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from . import autotune, lsh_probe as _lsh_probe, packfmt, ref
 from . import query_fused as _query_fused
 from .cminhash_kernel import cminhash_pallas
@@ -71,6 +72,48 @@ DENSE_IMPLS = ("auto", "int8", "packed", "ref")
 SPARSE_IMPLS = ("auto", "pallas", "windows", "gather")
 PROBE_IMPLS = ("auto", "numpy", "jnp", "pallas")
 QUERY_IMPLS = ("auto", "jnp", "pallas", "host")
+
+
+class _Family(dict):
+    """``kernel.<family>.<impl>`` call counters, each bound on first use."""
+
+    def __init__(self, reg, family: str):
+        super().__init__()
+        self._reg, self._prefix = reg, f"kernel.{family}."
+
+    def __missing__(self, impl: str):
+        c = self[impl] = self._reg.counter(self._prefix + impl)
+        return c
+
+
+class _Obs:
+    """This layer's instruments, bound once per (registry, tracer): which
+    impl served each call (per-resolved-impl counts: which kernel actually
+    serves the fleet), the query legs' timers and the spill counter."""
+
+    def __init__(self, reg, tracer):
+        self.reg, self.tracer = reg, tracer
+        (self.dense, self.sparse, self.probe, self.fold, self.query_fused,
+         self.score) = (_Family(reg, f) for f in (
+             "dense", "sparse", "probe", "fold", "query_fused", "score"))
+        self.t_operands, self.t_spill, self.t_readback = (
+            obs_trace.Timer(n, reg, tracer)
+            for n in ("query.operands", "query.spill", "query.readback"))
+        self.spill_rows = reg.counter("query.spill_rows")
+
+
+_OBS: _Obs | None = None
+
+
+def _obs() -> _Obs:
+    """The instruments of the current default registry and tracer (bound
+    anew only when either is swapped)."""
+    global _OBS
+    reg, tracer = obs_metrics.default(), obs_trace.default()
+    o = _OBS
+    if o is None or o.reg is not reg or o.tracer is not tracer:
+        o = _OBS = _Obs(reg, tracer)
+    return o
 
 
 def _backend() -> str:
@@ -129,8 +172,7 @@ def signatures_dense(v: Array, pi: Array, k: int, sigma: Array | None = None,
         raise ValueError(f"impl must be one of {DENSE_IMPLS} (got {impl!r})")
     if impl == "auto":
         impl = select_dense_impl(v.shape[-1], use_kernel=use_kernel)
-    # per-resolved-impl call counts: which kernel actually serves the fleet
-    obs_metrics.default().counter(f"kernel.dense.{impl}").inc()
+    _obs().dense[impl].inc()
     if sigma is not None:
         v = apply_permutation_dense(v, sigma)
     b, d = v.shape
@@ -161,7 +203,7 @@ def signatures_sparse(idx: Array, pi: Array, k: int,
         raise ValueError(f"impl must be one of {SPARSE_IMPLS} (got {impl!r})")
     if impl == "auto":
         impl = select_sparse_impl(use_kernel=use_kernel)
-    obs_metrics.default().counter(f"kernel.sparse.{impl}").inc()
+    _obs().sparse[impl].inc()
     if sigma is not None:
         idx = apply_permutation_sparse(idx, sigma)
     b, nnz = idx.shape
@@ -219,13 +261,15 @@ def lsh_probe(records_dev: Array, hashes: np.ndarray, *, n_slots: int,
         raise ValueError(f"impl must be one of {PROBE_IMPLS} (got {impl!r})")
     if impl == "auto":
         impl = "jnp"            # the device twin on every backend (above)
-    obs_metrics.default().counter(f"kernel.probe.{impl}").inc()
+    o = _obs()
+    o.probe[impl].inc()
     if impl == "numpy":
         raise ValueError("impl='numpy' is BandedLSHTable.lookup's own host "
                          "loop; call the table, not the dispatch layer")
     q, nb = hashes.shape
     w = records_dev.shape[1] - 2
-    meta = jnp.asarray(_lsh_probe.probe_operands(hashes, n_slots))
+    with o.t_operands:
+        meta = jnp.asarray(_lsh_probe.probe_operands(hashes, n_slots))
     if impl == "jnp":
         out = _lsh_probe.lsh_probe_jnp(records_dev, meta, n_slots=n_slots,
                                        max_probes=max_probes)
@@ -234,7 +278,8 @@ def lsh_probe(records_dev: Array, hashes: np.ndarray, *, n_slots: int,
                                           max_probes=max_probes,
                                           block_e=block_e,
                                           interpret=_interpret())
-    return np.asarray(out).reshape(q, nb * w)
+    with o.t_readback:
+        return np.asarray(out).reshape(q, nb * w)
 
 
 # -- fused device-resident query path -----------------------------------------
@@ -275,12 +320,15 @@ def fold_hashes(qwords: Array, *, n_bands: int, impl: str = "auto",
     if impl == "host":
         raise ValueError("impl='host' is core.lsh.band_hashes_packed; call "
                          "it directly, not the dispatch layer")
-    obs_metrics.default().counter(f"kernel.fold.{impl}").inc()
+    o = _obs()
+    o.fold[impl].inc()
     rows_hi, rows_lo = _query_fused.words_to_planes(jnp.asarray(qwords),
                                                     n_bands)
     hi, lo = _fold_planes(rows_hi, rows_lo, impl=impl, block_q=block_q,
                           autotune_measure=autotune_measure)
-    return _query_fused.planes_to_hashes(np.asarray(hi), np.asarray(lo))
+    with o.t_readback:
+        hi, lo = np.asarray(hi), np.asarray(lo)
+    return _query_fused.planes_to_hashes(hi, lo)
 
 
 def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
@@ -318,8 +366,8 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
     if impl == "host":
         raise ValueError("impl='host' is the store's legacy fold + planner "
                          "walk; call the store, not the dispatch layer")
-    reg = obs_metrics.default()
-    reg.counter(f"kernel.query_fused.{impl}").inc()
+    o = _obs()
+    o.query_fused[impl].inc()
     # the query batch follows the store state to its device (a shard of the
     # in-process plane may live on any device of the host)
     qwords = jax.device_put(jnp.asarray(qwords), words_dev.sharding)
@@ -327,21 +375,23 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
     w = records_dev.shape[1] - 2
 
     if hashes is None:
-        reg.counter(f"kernel.fold.{impl}").inc()
+        o.fold[impl].inc()
         rows_hi, rows_lo = _query_fused.words_to_planes(qwords, n_bands)
         hi, lo = _fold_planes(rows_hi, rows_lo, impl=impl, block_q=block_q,
                               autotune_measure=autotune_measure)
         meta = _query_fused.meta_from_planes(hi, lo, n_slots=n_slots)
         if spill_lookup is not None:   # rare host leg needs uint64 hashes
-            hashes = _query_fused.planes_to_hashes(np.asarray(hi),
-                                                   np.asarray(lo))
+            with o.t_readback:
+                hashes = _query_fused.planes_to_hashes(np.asarray(hi),
+                                                       np.asarray(lo))
     else:
-        meta = jnp.asarray(_lsh_probe.probe_operands(hashes, n_slots))
+        with o.t_operands:
+            meta = jnp.asarray(_lsh_probe.probe_operands(hashes, n_slots))
 
     # the probe leg takes the Pallas kernel only off TPU (interpret mode):
     # on TPU its VMEM-resident records cannot fit (select_probe_impl)
     probe = "pallas" if impl == "pallas" and _backend() != "tpu" else "jnp"
-    reg.counter(f"kernel.probe.{probe}").inc()
+    o.probe[probe].inc()
     if probe == "pallas":
         blocks = _resolve_blocks("probe_pallas", meta.shape[0], n_slots, w,
                                  {"block_e": block_e}, autotune_measure)
@@ -353,12 +403,17 @@ def query_fused(records_dev: Array, words_dev: Array, qwords: Array, *,
                                         max_probes=max_probes)
     cand = cand.reshape(q, n_bands * w)
     if spill_lookup is not None:
-        spill = np.asarray(spill_lookup(hashes))
-        if spill.size:
-            cand = jnp.concatenate(
-                [cand, jnp.asarray(spill.astype(np.int32))], axis=1)
-    reg.counter("kernel.score.jnp").inc()
+        with o.t_spill:
+            spill = np.asarray(spill_lookup(hashes))
+            if spill.size:
+                # a widened row widens the whole batch's candidate rows:
+                # score_topk compiles once per (batch, width)
+                o.spill_rows.inc(int((spill >= 0).any(axis=1).sum()))
+                cand = jnp.concatenate(
+                    [cand, jnp.asarray(spill.astype(np.int32))], axis=1)
+    o.score["jnp"].inc()
     ids, scores, has = _query_fused.score_topk(cand, words_dev, qwords,
                                                k=k, b=b, top_k=top_k)
-    return (np.asarray(ids).astype(np.int64), np.asarray(scores),
-            np.asarray(has))
+    with o.t_readback:
+        return (np.asarray(ids).astype(np.int64), np.asarray(scores),
+                np.asarray(has))

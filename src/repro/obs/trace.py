@@ -19,6 +19,15 @@ two int fields on the request frame, the worker opens its spans under that
 parent, and the reply echoes the worker's finished spans back as a JSON
 field next to the echoed seq — ``Tracer.absorb`` folds them into the
 coordinator's ring, producing one stitched trace (``for_trace``).
+
+``Timer`` is the one way a layer boundary of the served query and ingest
+paths is timed.  Bound once at component construction, entered as ``with
+self._t_fold:``, it opens a ``jax.profiler.TraceAnnotation`` of its name
+(host events on the device trace's clock when a profiler session runs;
+nothing when none does), opens the sampled ``Tracer.span`` of the same
+name, takes one ``perf_counter`` pair and observes the registry histogram
+of the same name.  ``SPANS`` is the catalogue of every timer name with the
+spans it nests under.
 """
 
 from __future__ import annotations
@@ -29,6 +38,8 @@ import random
 import threading
 import time
 from typing import NamedTuple
+
+from . import metrics as obs_metrics
 
 
 class TraceCtx(NamedTuple):
@@ -215,3 +226,105 @@ def current() -> TraceCtx | None:
     """Ambient trace context of the default tracer (the wire-injection
     hook: remote backends call this at submit time)."""
     return _default.current()
+
+
+# -- timed legs ---------------------------------------------------------------
+
+# The span catalogue: every ``Timer`` name of the served query and ingest
+# paths -> the spans it may open under (empty: opened at the top of its
+# thread).  Call sites and histograms are listed in README.md.
+SPANS: dict[str, tuple[str, ...]] = {
+    # stream front (serve/stream.py), on the coalescer thread
+    "stream.idle": (),
+    "stream.collect": (),
+    "stream.dispatch": (),
+    "stream.drain": (),
+    "stream.resolve": ("stream.drain",),
+    # service front door (serve/search.py)
+    "service.query": (),
+    "service.sign": ("service.query",),
+    # store query plane (store/sharded.py, kernels/dispatch.py)
+    "store.query": ("stream.drain", "service.query"),
+    "query.fold": ("store.query",),
+    "query.wall": ("store.query",),
+    "query.broadcast": ("query.wall", "query.brute"),
+    "query.partial": ("query.wall", "query.brute"),
+    "query.operands": ("query.partial",),
+    "query.spill": ("query.partial",),
+    "query.readback": ("query.fold", "store.query", "query.partial"),
+    "query.merge": ("query.wall",),
+    "query.brute": ("query.wall",),
+    # ingest pipeline (serve/search.py IngestPipeline)
+    "ingest.wall": (),
+    "ingest.sign": ("ingest.wall",),
+    "ingest.wait": ("ingest.wall",),
+    "ingest.scatter": ("ingest.wall",),
+}
+
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, or False: no JAX
+_frames = threading.local()  # per-thread stack of open (annotation, span, t0)
+_clock = time.perf_counter
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on first use so this
+    module imports without JAX; False where JAX is not installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class Timer:
+    """One named leg: profiler annotation + sampled span + histogram.
+
+    Bind once (``self._t_fold = Timer("query.fold")``) and enter per call;
+    ``__enter__`` returns the span (``NULL_SPAN`` when unsampled), so roots
+    can still ``tag`` it.  ``last`` is the most recent duration in seconds
+    (on any thread), kept on the timer itself so it reads true with the
+    registry disabled.  The annotation is made only while a profiler
+    session records (one static check otherwise), so the timer needs no
+    switch of its own.  Entries on one thread nest, so one per-thread stack
+    serves every timer and a timer may be entered from several threads."""
+
+    __slots__ = ("name", "last", "_hist", "_tracer", "_ann")
+
+    def __init__(self, name: str, registry=None,
+                 tracer: "Tracer | None" = None):
+        self.name = name
+        self.last = 0.0
+        reg = registry if registry is not None else obs_metrics.default()
+        self._hist = reg.histogram(name)
+        self._tracer = tracer if tracer is not None else default()
+        self._ann = _annotation_cls()
+
+    def __enter__(self):
+        ann = self._ann
+        if ann and ann.is_enabled():
+            ann = ann(self.name)
+            ann.__enter__()
+        else:
+            ann = None
+        span = self._tracer.span(self.name)
+        if span is not NULL_SPAN:
+            span.__enter__()
+        try:
+            stack = _frames.stack
+        except AttributeError:
+            stack = _frames.stack = []
+        stack.append((ann, span, _clock()))
+        return span
+
+    def __exit__(self, *exc) -> None:
+        t1 = _clock()
+        ann, span, t0 = _frames.stack.pop()
+        self.last = t1 - t0
+        self._hist.observe(self.last)
+        if span is not NULL_SPAN:
+            span.__exit__(*exc)
+        if ann is not None:
+            ann.__exit__(*exc)

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -126,10 +125,9 @@ class SimilaritySearchService:
             self.store = ShardedSketchStore(
                 store_cfg, n_shards=cfg.n_shards, partition=cfg.partition,
                 probe_impl=cfg.probe_impl, query_impl=cfg.query_impl)
-        self._tracer = obs_trace.default()
-        reg = obs_metrics.default()
-        self._h_query = reg.histogram("service.query")
-        self._h_sign = reg.histogram("service.sign")
+        reg, tracer = obs_metrics.default(), obs_trace.default()
+        self._t_query = obs_trace.Timer("service.query", reg, tracer)
+        self._t_sign = obs_trace.Timer("service.sign", reg, tracer)
 
     def _build_replicated(self, store_cfg: StoreConfig) -> None:
         """The replicated tcp plane: an S x R worker grid, a write-ahead
@@ -227,21 +225,16 @@ class SimilaritySearchService:
         everything under ``_query`` — fold, broadcast, per-shard partials
         (worker-side over tcp), merge — nests beneath it, stitching one
         cross-process trace per sampled query batch."""
-        t_wall = time.perf_counter()
-        with self._tracer.span("query") as root:
+        with self._t_query as root:
             root.tag("n", len(data)).tag("top_k", top_k)
-            t0 = time.perf_counter()
-            with self._tracer.span("query.sign"):
+            with self._t_sign:
                 qsigned = self._sign(data, layout)
                 if not (self.packed_ingest and self.cfg.query_impl != "host"):
                     # legacy paths want the host batch here; the fused path
                     # keeps it device-resident into the store's fold and
                     # syncs only for the shard broadcast
                     qsigned = np.asarray(qsigned)
-            self._h_sign.observe(time.perf_counter() - t0)
-            out = self._query(qsigned, top_k)
-        self._h_query.observe(time.perf_counter() - t_wall)
-        return out
+            return self._query(qsigned, top_k)
 
     def _query(self, qsigned: np.ndarray, top_k: int):
         """Returns (ids (Q, top_k) int64 [-1 pad], scores (Q, top_k) f32).
@@ -315,12 +308,14 @@ class IngestPipeline:
     The wall-time split lives in the process registry as per-batch latency
     HISTOGRAMS — ``ingest.sign`` (dispatch), ``ingest.wait`` (device sync —
     small when scatter covered the compute), ``ingest.scatter`` (store
-    writes), ``ingest.wall`` — so tail behavior (one slow scatter among
-    hundreds) is visible, not averaged away.  ``timings`` is a compatibility
-    view over the same observations: the familiar ``{sign_s, wait_s,
-    scatter_s, wall_s, n_batches, n_items}`` dict, scoped to THIS pipeline
-    by registry deltas from its construction (counts are plain ints, so
-    ``timings["n_items"]`` works even with the registry disabled).
+    writes), ``ingest.wall`` — each taken by the ``obs.trace.Timer`` of the
+    same name, which also marks the leg on a profiler trace — so tail
+    behavior (one slow scatter among hundreds) is visible, not averaged
+    away.  ``timings`` is a compatibility view over the same observations:
+    the familiar ``{sign_s, wait_s, scatter_s, wall_s, n_batches,
+    n_items}`` dict, scoped to THIS pipeline by registry deltas from its
+    construction (counts are plain ints, so ``timings["n_items"]`` works
+    even with the registry disabled).
     """
 
     _STAGES = ("sign", "wait", "scatter", "wall")
@@ -335,7 +330,9 @@ class IngestPipeline:
         self.depth = depth
         self.layout = layout
         self._inflight: collections.deque = collections.deque()
-        reg = obs_metrics.default()
+        reg, tracer = obs_metrics.default(), obs_trace.default()
+        self._t = {s: obs_trace.Timer(f"ingest.{s}", reg, tracer)
+                   for s in self._STAGES}
         self._h = {s: reg.histogram(f"ingest.{s}") for s in self._STAGES}
         self._base = {s: self._h[s].sum for s in self._STAGES}
         self.n_batches = 0
@@ -356,31 +353,27 @@ class IngestPipeline:
 
     def submit(self, batch) -> None:
         """Sign one batch (async) and scatter whatever is due."""
-        t0 = time.perf_counter()
-        signed = self.service._sign(batch, self.layout)
-        self._h["sign"].observe(time.perf_counter() - t0)
-        self._inflight.append((signed, len(batch)))
-        while len(self._inflight) >= self.depth:
-            self._drain_one()
-        self._h["wall"].observe(time.perf_counter() - t0)
+        with self._t["wall"]:
+            with self._t["sign"]:
+                signed = self.service._sign(batch, self.layout)
+            self._inflight.append((signed, len(batch)))
+            while len(self._inflight) >= self.depth:
+                self._drain_one()
 
     def _drain_one(self) -> None:
         signed, n = self._inflight.popleft()
-        t0 = time.perf_counter()
-        host = np.asarray(signed)          # sync: outstanding device work
-        t1 = time.perf_counter()
-        self.service._scatter(host)
-        self._h["wait"].observe(t1 - t0)
-        self._h["scatter"].observe(time.perf_counter() - t1)
+        with self._t["wait"]:
+            host = np.asarray(signed)      # sync: outstanding device work
+        with self._t["scatter"]:
+            self.service._scatter(host)
         self.n_batches += 1
         self.n_items += n
 
     def flush(self) -> None:
         """Drain every in-flight batch (the pipeline stays usable)."""
-        t0 = time.perf_counter()
-        while self._inflight:
-            self._drain_one()
-        self._h["wall"].observe(time.perf_counter() - t0)
+        with self._t["wall"]:
+            while self._inflight:
+                self._drain_one()
 
     def __enter__(self) -> "IngestPipeline":
         return self

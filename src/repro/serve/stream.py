@@ -58,6 +58,7 @@ import time
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.transport.client import (DeadlineExceeded, Overloaded,
                                     TransportError, deadline_scope)
 
@@ -183,6 +184,11 @@ class StreamingQueryService:
         self._g_depth = reg.gauge("stream.queue_depth")
         self._c_flush = {r: reg.counter(f"stream.flush.{r}")
                          for r in FLUSH_REASONS}
+        tracer = obs_trace.default()
+        (self._t_idle, self._t_collect, self._t_dispatch, self._t_drain,
+         self._t_resolve) = (obs_trace.Timer(f"stream.{n}", reg, tracer)
+                             for n in ("idle", "collect", "dispatch",
+                                       "drain", "resolve"))
         self.n_batches = 0
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="stream-query")
@@ -238,17 +244,23 @@ class StreamingQueryService:
         return t
 
     # -- the coalescer thread ------------------------------------------------
+    def _idle_locked(self) -> bool:
+        return not self._q and not self._closed and not self._inflight
+
     def _run(self) -> None:
         while True:
             with self._cond:
-                while not self._q and not self._closed and not self._inflight:
-                    self._cond.wait()
+                if self._idle_locked():
+                    with self._t_idle:
+                        while self._idle_locked():
+                            self._cond.wait()
                 if not self._q and not self._inflight and self._closed:
                     return
                 batch = reason = None
                 deadline_pending = False
                 if self._q:
-                    batch, reason = self._collect_locked()
+                    with self._t_collect:
+                        batch, reason = self._collect_locked()
                     deadline_pending = batch is None
             if batch is not None:
                 self._dispatch(batch, reason)
@@ -295,39 +307,40 @@ class StreamingQueryService:
         return min(1 << (n - 1).bit_length(), self.cfg.max_batch)
 
     def _dispatch(self, tickets: list[QueryTicket], reason: str) -> None:
-        # a ticket whose deadline passed while queued is dead weight: its
-        # caller is gone, so it is dropped before any signing work happens
-        now = time.time()
-        live = []
-        for t in tickets:
-            if t.deadline is not None and now >= t.deadline:
-                self._c_expired.inc()
-                t._reject(DeadlineExceeded(
-                    "query deadline passed while queued: dropped before "
-                    "dispatch"))
-            else:
-                live.append(t)
-        self._c_flush[reason].inc()
-        if not live:
-            return
-        tickets = live
-        rows = np.stack([t.row for t in tickets])
-        n_pad = self._pad_to(len(tickets)) - len(tickets)
-        if n_pad:
-            rows = np.concatenate(
-                [rows, np.broadcast_to(rows[:1],
-                                       (n_pad,) + rows.shape[1:])])
-        try:
-            signed = self.service._sign(rows, tickets[0].layout)  # async
-        except Exception as e:
+        with self._t_dispatch:
+            # a ticket whose deadline passed while queued is dead weight: its
+            # caller is gone, so it is dropped before any signing work happens
+            now = time.time()
+            live = []
             for t in tickets:
-                t._reject(e)
-            return
-        self._h_batch.observe(len(tickets))
-        now = time.perf_counter()
-        for t in tickets:
-            self._h_qwait.observe(now - t.t_submit)
-        self._inflight.append((signed, tickets))
+                if t.deadline is not None and now >= t.deadline:
+                    self._c_expired.inc()
+                    t._reject(DeadlineExceeded(
+                        "query deadline passed while queued: dropped before "
+                        "dispatch"))
+                else:
+                    live.append(t)
+            self._c_flush[reason].inc()
+            if not live:
+                return
+            tickets = live
+            rows = np.stack([t.row for t in tickets])
+            n_pad = self._pad_to(len(tickets)) - len(tickets)
+            if n_pad:
+                rows = np.concatenate(
+                    [rows, np.broadcast_to(rows[:1],
+                                           (n_pad,) + rows.shape[1:])])
+            try:
+                signed = self.service._sign(rows, tickets[0].layout)  # async
+            except Exception as e:
+                for t in tickets:
+                    t._reject(e)
+                return
+            self._h_batch.observe(len(tickets))
+            now = time.perf_counter()
+            for t in tickets:
+                self._h_qwait.observe(now - t.t_submit)
+            self._inflight.append((signed, tickets))
 
     def _budget(self):
         """The plane's shared ``RetryBudget``, when the store has one (a
@@ -392,29 +405,32 @@ class StreamingQueryService:
         raise last
 
     def _drain_one(self) -> None:
-        signed, tickets = self._inflight.popleft()
-        svc = self.service
-        try:
-            if not (svc.packed_ingest and svc.cfg.query_impl != "host"):
-                # legacy paths take the host batch; the fused path keeps
-                # the signed words device-resident into the store's fold
-                # (mirrors _traced_query)
-                signed = np.asarray(signed)
-            top_k = max(t.top_k for t in tickets)
-            ids, scores = self._query_with_retry(
-                svc, signed, top_k, self._batch_deadline(tickets))
-            ids, scores = np.asarray(ids), np.asarray(scores)
-        except Exception as e:
-            # one batch's failure answers its own tickets and nothing else;
-            # the coalescer keeps serving
-            for t in tickets:
-                t._reject(e)
-            return
-        for i, t in enumerate(tickets):
-            t._resolve(ids[i, :t.top_k].copy(), scores[i, :t.top_k].copy())
-            self._h_e2e.observe(t.t_done - t.t_submit)
-        self._c_queries.inc(len(tickets))
-        self.n_batches += 1
+        with self._t_drain:
+            signed, tickets = self._inflight.popleft()
+            svc = self.service
+            try:
+                if not (svc.packed_ingest and svc.cfg.query_impl != "host"):
+                    # legacy paths take the host batch; the fused path keeps
+                    # the signed words device-resident into the store's fold
+                    # (mirrors _traced_query)
+                    signed = np.asarray(signed)
+                top_k = max(t.top_k for t in tickets)
+                ids, scores = self._query_with_retry(
+                    svc, signed, top_k, self._batch_deadline(tickets))
+                ids, scores = np.asarray(ids), np.asarray(scores)
+            except Exception as e:
+                # one batch's failure answers its own tickets and nothing else;
+                # the coalescer keeps serving
+                for t in tickets:
+                    t._reject(e)
+                return
+            with self._t_resolve:
+                for i, t in enumerate(tickets):
+                    t._resolve(ids[i, :t.top_k].copy(),
+                               scores[i, :t.top_k].copy())
+                    self._h_e2e.observe(t.t_done - t.t_submit)
+            self._c_queries.inc(len(tickets))
+            self.n_batches += 1
 
     # -- lifecycle -----------------------------------------------------------
     def close(self) -> None:

@@ -274,17 +274,20 @@ class ShardedSketchStore:
         self.last_timings: dict[str, float] = {}
         # set when a partial write left coordinator/worker state divergent
         self._failed: str | None = None
-        # registry handles bound once; per-shard partial-latency histograms
-        # are the skew evidence load-aware rebalancing will consume
-        reg = obs_metrics.default()
-        self._h_fold = reg.histogram("query.fold")
-        self._h_broadcast = reg.histogram("query.broadcast")
-        self._h_partial = reg.histogram("query.partial")
-        self._h_merge = reg.histogram("query.merge")
-        self._h_query = reg.histogram("query.wall")
+        # registry handles and timers bound once; per-shard partial-latency
+        # histograms are the skew evidence load-aware rebalancing will consume
+        reg, tracer = obs_metrics.default(), obs_trace.default()
+        self._t_store = obs_trace.Timer("store.query", reg, tracer)
+        self._t_fold = obs_trace.Timer("query.fold", reg, tracer)
+        self._t_readback = obs_trace.Timer("query.readback", reg, tracer)
+        self._t_wall = obs_trace.Timer("query.wall", reg, tracer)
+        self._t_broadcast = obs_trace.Timer("query.broadcast", reg, tracer)
+        self._t_partial = obs_trace.Timer("query.partial", reg, tracer)
+        self._t_merge = obs_trace.Timer("query.merge", reg, tracer)
+        self._t_brute = obs_trace.Timer("query.brute", reg, tracer)
+        self._c_brute_rows = reg.counter("query.brute_rows")
         self._h_shard = [reg.histogram(shard_partial_hist_name(i))
                          for i in range(n_shards)]
-        self._tracer = obs_trace.default()
 
     # -- sizing ------------------------------------------------------------
     @property
@@ -452,18 +455,13 @@ class ShardedSketchStore:
         The broadcast span is ambient while legs are submitted, so remote
         workers' spans nest under it in the stitched trace.
         """
-        t0 = time.perf_counter()
-        with self._tracer.span("query.broadcast"):
+        with self._t_broadcast:
             pend = [start(sh) for sh in self.shards]
-        t1 = time.perf_counter()
-        with self._tracer.span("query.partial"):
+        with self._t_partial:
             parts = [self._to_global(s, p.result())
                      for s, p in enumerate(pend)]
-        t2 = time.perf_counter()
-        tally["broadcast_s"] += t1 - t0
-        tally["partial_s"] += t2 - t1
-        self._h_broadcast.observe(t1 - t0)
-        self._h_partial.observe(t2 - t1)
+        tally["broadcast_s"] += self._t_broadcast.last
+        tally["partial_s"] += self._t_partial.last
         for s, p in enumerate(pend):
             lat = getattr(p, "latency_s", None)
             if lat is not None:
@@ -477,34 +475,30 @@ class ShardedSketchStore:
         global brute-force leg for rows with no candidates anywhere.
         ``fold_s`` is the caller's already-spent band-hash fold time, folded
         into the timing split so every query stage is accounted for."""
-        wall_t0 = time.perf_counter()
-        tally = {"fold_s": fold_s, "broadcast_s": 0.0, "partial_s": 0.0,
-                 "merge_s": 0.0}
-        self._h_fold.observe(fold_s)
-        parts = self._fanout(
-            lambda sh: sh.start_query(hashes, qwords, top_k, mode), tally)
-        has_any = np.zeros(len(qwords), bool)
-        for p in parts:
-            has_any |= p.has_candidates
-        t0 = time.perf_counter()
-        with self._tracer.span("query.merge"):
-            scores, ids = merge_topk([p.scores for p in parts],
-                                     [p.ids for p in parts], top_k)
-        tally["merge_s"] += time.perf_counter() - t0
-        em = np.flatnonzero(~has_any)
-        if len(em) and self.n_items:
-            brute = self._fanout(
-                lambda sh: sh.start_brute(qwords[em], top_k), tally)
-            t0 = time.perf_counter()
-            with self._tracer.span("query.merge"):
-                b_scores, b_ids = merge_topk([p.scores for p in brute],
-                                             [p.ids for p in brute], top_k)
-            scores[em] = b_scores
-            ids[em] = b_ids
-            tally["merge_s"] += time.perf_counter() - t0
-        self.last_timings = tally
-        self._h_merge.observe(tally["merge_s"])
-        self._h_query.observe(time.perf_counter() - wall_t0)
+        with self._t_wall:
+            tally = {"fold_s": fold_s, "broadcast_s": 0.0, "partial_s": 0.0,
+                     "merge_s": 0.0}
+            parts = self._fanout(
+                lambda sh: sh.start_query(hashes, qwords, top_k, mode), tally)
+            has_any = np.zeros(len(qwords), bool)
+            for p in parts:
+                has_any |= p.has_candidates
+            em = np.flatnonzero(~has_any)
+            brute = None
+            if len(em) and self.n_items:
+                self._c_brute_rows.inc(len(em))
+                with self._t_brute:
+                    brute = self._fanout(
+                        lambda sh: sh.start_brute(qwords[em], top_k), tally)
+            with self._t_merge:
+                scores, ids = merge_topk([p.scores for p in parts],
+                                         [p.ids for p in parts], top_k)
+                if brute is not None:
+                    scores[em], ids[em] = merge_topk(
+                        [p.scores for p in brute], [p.ids for p in brute],
+                        top_k)
+            tally["merge_s"] = self._t_merge.last
+            self.last_timings = tally
         return finalize_topk(TopKPartial(ids, scores, has_any))
 
     def query(self, qsigs: np.ndarray,
@@ -518,16 +512,15 @@ class ShardedSketchStore:
         qsigs = np.asarray(qsigs)
         # store.query is the root when nobody upstream opened one (a direct
         # store caller still gets one stitched trace); under the service's
-        # "query" span it just nests
-        with self._tracer.span("store.query"):
-            t0 = time.perf_counter()
-            with self._tracer.span("query.fold"):
+        # "service.query" span it just nests
+        with self._t_store:
+            with self._t_fold:
                 hashes = band_hashes(qsigs, self.cfg.n_bands,
                                      self.cfg.rows_per_band)
                 qwords = np.asarray(
                     ops.pack_codes(jnp.asarray(qsigs, jnp.int32), self.cfg.b))
             return self._merged_query(hashes, qwords, top_k, "sig",
-                                      fold_s=time.perf_counter() - t0)
+                                      fold_s=self._t_fold.last)
 
     def query_packed(self, qwords: np.ndarray,
                      top_k: int = 10) -> tuple[np.ndarray, np.ndarray]:
@@ -541,12 +534,12 @@ class ShardedSketchStore:
         needs anyway."""
         self._check_queryable("query_packed()")
         check_packed_banding(self.cfg)
-        with self._tracer.span("store.query"):
-            t0 = time.perf_counter()
-            with self._tracer.span("query.fold"):
+        with self._t_store:
+            with self._t_fold:
                 hashes = self._fold_packed(qwords)
-            fold_s = time.perf_counter() - t0
-            qwords = np.asarray(qwords, np.uint32)
+            fold_s = self._t_fold.last
+            with self._t_readback:
+                qwords = np.asarray(qwords, np.uint32)
             return self._merged_query(hashes, qwords, top_k, "packed",
                                       fold_s=fold_s)
 

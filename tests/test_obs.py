@@ -11,6 +11,7 @@ share a trace id (the stitched sign->shard->serve trace).
 """
 
 import json
+import os
 import time
 
 import numpy as np
@@ -138,6 +139,66 @@ def test_disabled_registry_is_noop_and_cheap():
         h.observe_n(2.0, 3)
     per_op = (time.perf_counter() - t0) / (2 * n)
     assert per_op < 5e-6, f"null instrument op cost {per_op * 1e9:.0f}ns"
+
+
+def test_timer_without_profiler_session_is_cheap():
+    """The span helper on a disabled registry and an unsampled tracer, with
+    no profiler session: no annotation is made, the span is the shared
+    no-op, and enter + exit stays a few microseconds."""
+    reg = obs_metrics.Registry(enabled=False)
+    t = obs_trace.Timer("query.fold", reg, obs_trace.Tracer(sample_rate=0.0))
+    with t as span:
+        assert span is obs_trace.NULL_SPAN
+    assert t.last >= 0.0 and reg.snapshot() == obs_metrics.empty_snapshot()
+
+    n = 50_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with t:
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    assert per_span < 10e-6, f"timer cost {per_span * 1e9:.0f}ns"
+
+
+def test_timer_feeds_histogram_span_and_last():
+    reg = obs_metrics.Registry()
+    tr = obs_trace.Tracer(sample_rate=1.0, proc="t")
+    outer = obs_trace.Timer("store.query", reg, tr)
+    inner = obs_trace.Timer("query.fold", reg, tr)
+    with outer as root:
+        root.tag("n", 3)
+        with inner:
+            time.sleep(0.002)
+    assert inner.last >= 0.002 and outer.last >= inner.last
+    snap = reg.snapshot()["hists"]
+    assert snap["query.fold"]["count"] == snap["store.query"]["count"] == 1
+    assert obs_metrics.hist_sum(snap["query.fold"]) == \
+        pytest.approx(inner.last, abs=1e-9)
+    spans = {s["name"]: s for s in tr.drain()}
+    assert spans["query.fold"]["parent"] == spans["store.query"]["span"]
+    assert spans["store.query"]["tags"] == {"n": 3}
+    # an exception still closes the leg and is not swallowed
+    with pytest.raises(KeyError):
+        with inner:
+            raise KeyError("x")
+    assert reg.snapshot()["hists"]["query.fold"]["count"] == 2
+
+
+def test_obs_imports_and_times_without_jax():
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro.obs import Timer, Registry\n"
+            "t = Timer('query.fold', Registry())\n"
+            "with t: pass\n"
+            "assert 'jax.profiler' not in sys.modules\n"
+            "print(t.last >= 0)\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=60,
+                       env=dict(os.environ, PYTHONPATH=src))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == "True"
 
 
 # -- dump files ---------------------------------------------------------------
