@@ -22,9 +22,10 @@ Two implementations of the same scan share the precompute helpers:
   the dispatchable fast path on CPU and the oracle-equivalent of the kernel.
 * ``cminhash_sparse_pallas`` — the Pallas kernel: grid over (batch tiles,
   nnz tiles), window table resident in VMEM as 128-lane rows, starts in
-  SMEM, each window read as two ref loads plus a lane rotation and
-  min-folded into the output block.  The window length is padded to the
-  128-lane geometry; ``interpret=True`` runs it on CPU.
+  SMEM, each window read as one ref load plus one lane rotation and
+  min-folded into two lane-masked accumulators, many windows in flight per
+  loop trip.  The window length is padded to the 128-lane geometry;
+  ``interpret=True`` runs it on CPU.
 
 Both are bit-identical to the gather path (same exact integer mins), and both
 take ``pack_b``: the b-bit truncate+pack (``packfmt.pack_codes``) runs inside
@@ -34,6 +35,7 @@ the same jit as the scan, so no (B, K) int32 crosses back to the host.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -149,28 +151,68 @@ def cminhash_sparse_windows(idx: Array, pi: Array, k: int,
 
 
 _LANES = 128
+# window slabs in flight per loop trip, counted in (8, 128) vregs: on a v5e
+# 16 one-vreg slabs ran 1.6x faster than 8, and 32 spill the accumulators
+_IN_FLIGHT = 16
+
+
+def loop_widths(bt: int, jt: int, nr: int) -> tuple[int, int]:
+    """(documents, nnz steps) the scan loop folds per trip, from the tile.
+
+    One trip keeps ``_IN_FLIGHT`` vregs of window slabs in flight: up to 8
+    documents of the tile side by side, the rest by unrolling the nnz loop
+    (a divisor of ``jt``).  A slab of nr + 1 lane rows fills
+    ceil((nr + 1) / 8) vregs, so taller windows keep fewer in flight."""
+    slabs = max(1, _IN_FLIGHT // -(-(nr + 1) // 8))
+    group = min(bt, 8, slabs)
+    return group, math.gcd(max(1, slabs // group), jt)
 
 
 def _kernel(table_ref, s_ref, out_ref, *, bt: int, jt: int, nr: int):
     """One (batch tile, nnz tile) step.  The table is (T, 128) lane rows;
     the window at flat start s = 128*r + c is rows r..r+nr rotated left by
-    c lanes, each lane taking row i or row i+1 by whether it wrapped."""
+    c lanes, each lane taking row i or row i+1 by whether it wrapped.
+
+    Each window is one load of the nr + 1 rows r..r+nr and one rotation.
+    Lane l of rotated row i belongs to output row i when l < 128 - c (it did
+    not wrap) and to output row i - 1 otherwise, so the slab is min-folded
+    into two accumulators by that lane mask, ``lo`` (row i) and ``hi`` (row
+    i - 1); output row i is min(lo[i], hi[i + 1]) once the tile's windows
+    are in.  Each loop trip folds ``group`` documents times ``unroll`` nnz
+    steps (``loop_widths``): that many independent load-roll-min chains are
+    in flight, where one window a step left each to wait out the last."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.full(out_ref.shape, SENTINEL, out_ref.dtype)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (nr, _LANES), 1)
-    for bl in range(bt):                              # static: one doc each
-        def body(jl, acc, bl=bl):
-            s = s_ref[bl, jl]                         # scalar from SMEM
-            r = s // _LANES
-            c = s - r * _LANES
-            shift = (_LANES - c) % _LANES             # roll left by c
-            lo = pltpu.roll(table_ref[pl.ds(r, nr), :], shift, 1)
-            hi = pltpu.roll(table_ref[pl.ds(r + 1, nr), :], shift, 1)
-            return jnp.minimum(acc, jnp.where(lane < _LANES - c, lo, hi))
+    group, unroll = loop_widths(bt, jt, nr)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nr + 1, _LANES), 1)
+    top = jnp.full((nr + 1, _LANES), SENTINEL, jnp.int32)
+    for g0 in range(0, bt, group):                    # static doc groups
+        docs = range(g0, min(g0 + group, bt))
 
-        out_ref[bl] = jax.lax.fori_loop(0, jt, body, out_ref[bl])
+        def body(i, accs, docs=docs):
+            for u in range(unroll):
+                jl = i * unroll + u
+                out = []
+                for bl, (lo, hi) in zip(docs, accs):
+                    # s >= 0: row and lane by shift and mask (a signed //
+                    # lowers to some 20 scalar ops per window)
+                    s = s_ref[bl, jl]
+                    c = s & (_LANES - 1)
+                    w = pltpu.roll(table_ref[pl.ds(s >> 7, nr + 1), :],
+                                   (_LANES - c) & (_LANES - 1), 1)
+                    keep = lane < _LANES - c
+                    out.append((jnp.minimum(lo, jnp.where(keep, w, top)),
+                                jnp.minimum(hi, jnp.where(keep, top, w))))
+                accs = tuple(out)
+            return accs
+
+        accs = jax.lax.fori_loop(0, jt // unroll, body,
+                                 tuple((top, top) for _ in docs))
+        for bl, (lo, hi) in zip(docs, accs):
+            out_ref[bl] = jnp.minimum(out_ref[bl],
+                                      jnp.minimum(lo[:nr], hi[1:]))
 
 
 @functools.partial(
@@ -193,9 +235,11 @@ def cminhash_sparse_pallas(idx: Array, pi: Array, k: int, *,
     VMEM-resident (T, 128) block (D + 2*Kp words — ~0.5 MB at D = 65536,
     K = 1024) and the window starts ride in SMEM, so the only HBM traffic
     per tile is the (Bt, Jt) start block and the (Bt, Kp/128, 128) output
-    min-accumulation.  A window is read with two sublane-offset ref loads
+    min-accumulation.  A window is read with one sublane-offset ref load
     and one lane rotation — no value-level dynamic slice, which Mosaic does
-    not lower.  Window length Kp is K padded to the 128-lane geometry.
+    not lower — and the loop folds ``loop_widths(block_b, block_j, Kp/128)``
+    windows per trip.  Window length Kp is K padded to the 128-lane
+    geometry.
     """
     if shift_offset not in (0, 1):
         raise ValueError("shift_offset must be 0 or 1")

@@ -160,21 +160,62 @@ def _sparse_from_dense(v):
     return jnp.asarray(idx)
 
 
-@pytest.mark.parametrize("B,D,K,dens", [
-    (1, 64, 1, 0.1), (2, 64, 64, 0.3), (4, 100, 37, 0.05), (3, 777, 300, 0.02),
-    (2, 300, 7, 0.05), (2, 96, 96, 0.9),
+def _case(B, D, K, dens, block_b=2, block_j=8, pack_b=None, empty_row=False):
+    tile = (block_b, block_j, pack_b, empty_row)
+    extra = "" if tile == (2, 8, None, False) else (
+        f"-b{block_b}j{block_j}" + (f"-pack{pack_b}" if pack_b else "")
+        + ("-empty" if empty_row else ""))
+    return pytest.param(B, D, K, dens, tile, id=f"{B}-{D}-{K}-{dens}{extra}")
+
+
+@pytest.mark.parametrize("B,D,K,dens,tile", [
+    _case(1, 64, 1, 0.1), _case(2, 64, 64, 0.3), _case(4, 100, 37, 0.05),
+    _case(3, 777, 300, 0.02), _case(2, 300, 7, 0.05), _case(2, 96, 96, 0.9),
+    # the served tile (block_b 8, block_j 128): a partial batch tile
+    # (B = 13), nnz not a multiple of 128, K over 1, 2 and 3 lane rows,
+    # packed outputs, a row that is all padding
+    _case(13, 777, 128, 0.2, 8, 128),
+    _case(13, 777, 256, 0.35, 8, 128),
+    _case(13, 777, 300, 0.2, 8, 128, empty_row=True),
+    _case(13, 777, 128, 0.35, 8, 128, pack_b=32, empty_row=True),
+    _case(13, 777, 256, 0.2, 8, 128, pack_b=8),
+    _case(5, 2048, 128, 0.1, 8, 128, pack_b=8, empty_row=True),
+    # the loop's other widths (documents x nnz steps a trip): 4 x 4, 1 x 16,
+    # two groups of 8 x 2 in a 16-row tile, and 8 x 1 for 9-row slabs
+    _case(6, 300, 128, 0.3, 4, 128),
+    _case(3, 300, 64, 0.3, 1, 128, empty_row=True),
+    _case(13, 777, 128, 0.2, 16, 128),
+    _case(3, 2048, 1024, 0.05, 8, 128),
 ])
 @pytest.mark.parametrize("off", [0, 1])
-def test_sparse_pallas_kernel_matches_ref(B, D, K, dens, off):
+def test_sparse_pallas_kernel_matches_ref(B, D, K, dens, tile, off):
     from repro.kernels.cminhash_sparse import cminhash_sparse_pallas
+    from repro.kernels.packfmt import pack_codes
+    block_b, block_j, pack_b, empty_row = tile
     rng = np.random.default_rng(B * D + K)
     v = (rng.random((B, D)) < dens).astype(np.int8)
+    if empty_row:
+        v[B // 2] = 0
     _, pi = make_two_permutations(jax.random.PRNGKey(0), D)
     got = cminhash_sparse_pallas(_sparse_from_dense(v), pi, K,
-                                 shift_offset=off, block_b=2, block_j=8,
-                                 interpret=True)
+                                 shift_offset=off, block_b=block_b,
+                                 block_j=block_j, interpret=True,
+                                 pack_b=pack_b)
     want = ref.cminhash_dense_ref(jnp.asarray(v), pi, K, shift_offset=off)
+    if pack_b is not None:
+        want = pack_codes(want, pack_b)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("bt,jt,nr,widths", [
+    (8, 128, 1, (8, 2)), (8, 128, 3, (8, 2)), (4, 128, 2, (4, 4)),
+    (2, 8, 1, (2, 8)), (1, 128, 1, (1, 16)), (16, 128, 1, (8, 2)),
+    (8, 128, 8, (8, 1)), (8, 7, 1, (8, 1)),
+])
+def test_sparse_loop_widths(bt, jt, nr, widths):
+    # 16 vregs of window slabs a trip, documents first, nnz steps dividing jt
+    from repro.kernels.cminhash_sparse import loop_widths
+    assert loop_widths(bt, jt, nr) == widths
 
 
 @settings(max_examples=15, deadline=None)

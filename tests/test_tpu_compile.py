@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import dispatch, lsh_probe, query_fused
+from repro.kernels import autotune, dispatch, lsh_probe, query_fused
 from repro.kernels.cminhash_sparse import cminhash_sparse_pallas
 from repro.kernels.collision_kernel import collision_count_pallas
 
@@ -56,11 +56,19 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_sparse_sign_compiles(one_chip):
+@pytest.mark.parametrize("b,k", [
+    (8192, 128),    # dedup-k128 bulk ingest batches
+    (Q, K),         # search-k256 stream batches
+    (16, 128),      # dedup-k128 lookup batches
+])
+def test_sparse_sign_compiles(one_chip, b, k):
     assert dispatch.select_sparse_impl(backend="tpu") == "pallas"
+    blocks = autotune.recommend("sparse_pallas", b, D, k, backend="tpu",
+                                nnz=NNZ)
     _, hlo = _compile(
-        partial(cminhash_sparse_pallas, k=K, pack_b=32, interpret=False),
-        _spec(one_chip, (Q, NNZ), jnp.int32), _spec(one_chip, (D,), jnp.int32))
+        partial(cminhash_sparse_pallas, k=k, pack_b=32, interpret=False,
+                **blocks),
+        _spec(one_chip, (b, NNZ), jnp.int32), _spec(one_chip, (D,), jnp.int32))
     assert "tpu_custom_call" in hlo
 
 
